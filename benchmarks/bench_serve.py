@@ -198,15 +198,15 @@ def _kernel_numerics() -> List[Dict[str, Any]]:
         nb = -(-L // bs)
         ks = jax.random.split(jax.random.PRNGKey(7), 3)
         q = jax.random.normal(ks[0], (1, H, D))
-        k_pages = jax.random.normal(ks[1], (nb + 1, bs, K, D))
-        v_pages = jax.random.normal(ks[2], (nb + 1, bs, K, D))
+        k_pages = jax.random.normal(ks[1], (nb + 1, K, bs, D))
+        v_pages = jax.random.normal(ks[2], (nb + 1, K, bs, D))
         bt = jnp.asarray(1 + np.arange(nb, dtype=np.int32))[None]
         out = flash_decode_paged(q, k_pages, v_pages, bt,
                                  jnp.asarray([L], jnp.int32),
                                  window=window, pages_per_split=2,
                                  interpret=True)
-        kd = k_pages[bt[0]].reshape(-1, K, D)[None, :L]
-        vd = v_pages[bt[0]].reshape(-1, K, D)[None, :L]
+        kd = jnp.swapaxes(k_pages[bt[0]], 1, 2).reshape(-1, K, D)[None, :L]
+        vd = jnp.swapaxes(v_pages[bt[0]], 1, 2).reshape(-1, K, D)[None, :L]
         qd = jnp.zeros((1, L, H, D)).at[:, L - 1].set(q[0])
         ref = chunked_attention(qd, kd, vd, causal=True, window=window,
                                 chunk=8)[0, L - 1]

@@ -32,7 +32,6 @@ import jax           # noqa: E402
 import jax.numpy as jnp   # noqa: E402
 import numpy as np   # noqa: E402
 
-from repro import compat                            # noqa: E402
 from repro.configs.opt import opt_config            # noqa: E402
 from repro.core.energy.devices import LAPTOP_M2PRO  # noqa: E402
 from repro.core.planner import dtfm                 # noqa: E402
@@ -40,6 +39,7 @@ from repro.data.pipeline import make_batch_fn       # noqa: E402
 from repro.distributed.pipeline import (            # noqa: E402
     make_pipeline_loss, pipeline_train_step, stack_for_stages,
     unstack_stages)
+from repro.launch.mesh import make_edge_mesh       # noqa: E402
 from repro.optim import adamw                       # noqa: E402
 
 
@@ -67,7 +67,7 @@ def main() -> None:
     print(f"model: {cfg.param_count()/1e6:.1f}M params, "
           f"{cfg.num_layers} layers")
 
-    mesh = jax.make_mesh((DATA, STAGES), ("data", "stage"))
+    mesh = make_edge_mesh(STAGES, DATA)
     print(f"mesh: {DATA} data x {STAGES} stages "
           f"({DATA*STAGES} simulated edge devices)")
 
@@ -76,7 +76,7 @@ def main() -> None:
     init_fn, step_fn = pipeline_train_step(
         cfg, mesh, opt_cfg, num_microbatches=args.microbatches)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         rest, staged, opt = init_fn(jax.random.PRNGKey(0))
         data = make_batch_fn(cfg, args.batch, args.seq, seed=0)
         losses = []
@@ -120,7 +120,7 @@ def main() -> None:
         from repro.models import params as PM                    # noqa: E402
         p = PM.init_params(cfg, jax.random.PRNGKey(1))
         st = stack_for_stages(cfg, p, spec)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             b = {k: jnp.asarray(v) for k, v in next(data).items()}
             nl = jax.jit(loss_fn)(p, st, b)
         print(f"  non-uniform split {spec.layer_counts} executes: "

@@ -6,18 +6,17 @@ don't exist or don't divide the dim are silently dropped, so model code can
 annotate once and run unchanged on a laptop (1 device), the edge mesh, or
 the 512-chip production mesh.
 
-Mesh introspection goes through :mod:`repro.compat`, so the same code is
-live on jax ≥ 0.5 (abstract mesh) and jax 0.4.x (physical-mesh context).
+The ambient mesh is the one ``jax.set_mesh`` installed; only its ``Auto``
+axes carry constraints (meshes come from :mod:`repro.launch.mesh`, which
+builds them Auto).
 """
 
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple, Union
+from typing import Tuple, Union
 
 import jax
-from jax.sharding import PartitionSpec as P
-
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 
 Part = Union[None, str, Tuple[str, ...]]
 
@@ -28,39 +27,29 @@ MODEL = ("model",)
 REPLICATED = "~replicated~"
 
 
+def _auto_axis_sizes() -> dict:
+    """{axis name: size} of the ambient mesh's Auto axes (empty without a
+    mesh, and inside ``shard_map`` where the axes are Manual)."""
+    am = jax.sharding.get_abstract_mesh()
+    return {n: s for n, s, t in zip(am.axis_names, am.axis_sizes,
+                                    am.axis_types)
+            if t == AxisType.Auto}
+
+
 def axis_extent(name: str) -> int:
     """Extent of a mesh axis in the ambient abstract mesh (1 if absent or
     not Auto) — lets model code pick sharding-dependent layouts at trace
     time without carrying the mesh around."""
-    try:
-        am = compat.get_abstract_mesh()
-    except Exception:
-        return 1
-    if am is None or not am.axis_names:
-        return 1
-    for n, s, t in zip(am.axis_names, am.axis_sizes, am.axis_types):
-        if n == name and t == compat.AxisType.Auto:
-            return s
-    return 1
+    return _auto_axis_sizes().get(name, 1)
 
 
 def constrain(x: jax.Array, *parts: Part) -> jax.Array:
     """Pin listed dims to mesh axes; unlisted/None dims stay UNCONSTRAINED
     so GSPMD remains free to shard them (crucial: a hard None would force
     replication and insert all-gathers against XLA's chosen layout)."""
-    try:
-        am = compat.get_abstract_mesh()
-    except Exception:
+    sizes = _auto_axis_sizes()
+    if not sizes:
         return x
-    if am is None or not am.axis_names:
-        return x
-    # only Auto axes can carry constraints; inside shard_map (Manual) no-op
-    # (compare enum values, NOT str(): str(AxisType.Auto)=="AxisType.Auto")
-    auto = {n for n, t in zip(am.axis_names, am.axis_types)
-            if t == compat.AxisType.Auto}
-    if not auto:
-        return x
-    sizes = {n: s for n, s in zip(am.axis_names, am.axis_sizes) if n in auto}
     used = set()
     clean = []
     pinned = False

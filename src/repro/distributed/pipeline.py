@@ -215,16 +215,18 @@ def make_pipeline_loss(cfg: ModelConfig, mesh: Mesh, *,
                                     jnp.arange(MB + S - 1))
         return outs[None]           # stacked over stage; stage S-1 is real
 
-    from jax.experimental.shard_map import shard_map
+    # check_vma=False: the stage body is the ordinary model code, whose
+    # scans (chunked attention, the layer scan) start their carries from
+    # zeros that do not vary over "stage" while the stage's activations do
     if mask_all is None:
-        sm3 = shard_map(lambda s, e, p: pipelined(s, e, p, None), mesh=mesh,
-                        in_specs=(P("stage"), P(), P()),
-                        out_specs=P("stage"), check_rep=False)
+        sm3 = jax.shard_map(lambda s, e, p: pipelined(s, e, p, None),
+                            mesh=mesh, in_specs=(P("stage"), P(), P()),
+                            out_specs=P("stage"), check_vma=False)
         sm = lambda s, e, p, _m: sm3(s, e, p)          # noqa: E731
     else:
-        sm = shard_map(pipelined, mesh=mesh,
-                       in_specs=(P("stage"), P(), P(), P("stage")),
-                       out_specs=P("stage"), check_rep=False)
+        sm = jax.shard_map(pipelined, mesh=mesh,
+                           in_specs=(P("stage"), P(), P(), P("stage")),
+                           out_specs=P("stage"), check_vma=False)
 
     def loss_fn(params, staged, batch):
         tokens, labels = batch["tokens"], batch["labels"]
@@ -260,13 +262,15 @@ def pipeline_train_step(cfg: ModelConfig, mesh: Mesh, opt_cfg, *,
     def init_fn(rng):
         params = PM.init_params(cfg, rng)
         staged = stack_for_stages(cfg, params, stages)
-        staged = jax.device_put(
-            staged, jax.tree.map(
-                lambda _: NamedSharding(
-                    mesh, P("stage")), staged))
+        staged = jax.device_put(staged, NamedSharding(mesh, P("stage")))
         rest = dict(params)
         del rest["decoder"]
+        # embedding / head / final norm run outside the pipelined region
+        # on every stage: replicate them over the mesh rather than leave
+        # them on the default device
+        rest = jax.device_put(rest, NamedSharding(mesh, P()))
         opt = adamw.init_opt_state({"rest": rest, "staged": staged}, opt_cfg)
+        opt["step"] = jax.device_put(opt["step"], NamedSharding(mesh, P()))
         return rest, staged, opt
 
     from repro.optim import adamw as A

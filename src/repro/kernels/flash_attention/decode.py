@@ -28,11 +28,19 @@ its sequence's cached K/V, which live in fixed-size **pages** (see
   same-step causality is just the per-row length mask.  All rows of a
   sequence share the page stream — one grid, ``C * group`` query rows
   per program.
-* **int8 KV** — with ``k_scale``/``v_scale`` pools of shape ``(P, bs,
-  K)`` the pages hold int8 values quantized per (page slot, kv head)
+* **int8 KV** — with ``k_scale``/``v_scale`` pools of shape ``(P, K,
+  bs)`` the pages hold int8 values quantized per (page slot, kv head)
   vector (``kernels/quant8`` blockwise scheme, quant block = head_dim);
-  the kernel dequantizes in registers right after the page load, so HBM
-  traffic stays at the int8 byte count.
+  the kernel applies the scales in registers to the score and
+  probability tiles (``s[:, t]·ks[t]``, ``(p·vs) @ v``), so HBM traffic
+  stays at the int8 byte count.
+* **Layout** — pages are head-major, ``(P, K, bs, D)``: one (kv head,
+  page) block is a whole ``(bs, D)`` tile, which is what a TPU block's
+  last two dims must be (multiples of (8, 128) or the whole dims); a
+  ``(P, bs, K, D)`` pool would put a 1-wide head slice in the
+  second-minor place, and pad every page ``K``-row slab to a full tile.
+  The per-split row statistics leave the kernel lane-broadcast
+  (``(..., CG, LANES)``) for the same reason.
 
 ``seq_lens`` counts **all** valid cache positions *including* the first
 query row's token (the engine scatters the new K/V at position
@@ -52,7 +60,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash_attention.kernel import NEG_INF
+from repro.kernels.flash_attention.kernel import LANES, NEG_INF
 
 DEFAULT_PAGES_PER_SPLIT = 8
 
@@ -88,10 +96,10 @@ def paged_attention_reference(q: jax.Array, k_pages: jax.Array,
     """Dense-gather oracle for the paged layout (fp32 softmax).
 
     q: (B, H, D) or (B, C, H, D) teacher-forced chunk rows; k/v_pages:
-    (P, bs, K, D*); block_tables: (B, NB) int32; seq_lens: (B,) int32
+    (P, K, bs, D*); block_tables: (B, NB) int32; seq_lens: (B,) int32
     valid positions incl. the first query row's token (row ``c`` of a
     chunk attends to ``seq_lens + c`` positions).  ``k_scale``/``v_scale``
-    ((P, bs, K) fp32) dequantize int8 pages.  Returns q's shape with D ->
+    ((P, K, bs) fp32) dequantize int8 pages.  Returns q's shape with D ->
     Dv.  Rows with seq_len == 0 return garbage (masked upstream) — padded
     engine slots are never read.
     """
@@ -99,18 +107,21 @@ def paged_attention_reference(q: jax.Array, k_pages: jax.Array,
     if squeeze:
         q = q[:, None]
     B, C, H, D = q.shape
-    P, bs, K, _ = k_pages.shape
+    P, K, bs, _ = k_pages.shape
     Dv = v_pages.shape[-1]
     g = H // K
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     T = block_tables.shape[1] * bs
-    k = k_pages[block_tables].reshape(B, T, K, D).astype(jnp.float32)
-    v = v_pages[block_tables].reshape(B, T, K, Dv).astype(jnp.float32)
+
+    def dense(pool):                  # (P, K, bs, ...) -> (B, T, K, ...)
+        x = jnp.swapaxes(pool[block_tables], 2, 3)
+        return x.reshape((B, T, K) + pool.shape[3:]).astype(jnp.float32)
+    k, v = dense(k_pages), dense(v_pages)
     if k_scale is not None:
-        k = k * k_scale[block_tables].reshape(B, T, K)[..., None]
+        k = k * dense(k_scale)[..., None]
     if v_scale is not None:
-        v = v * v_scale[block_tables].reshape(B, T, K)[..., None]
+        v = v * dense(v_scale)[..., None]
     qf = q.reshape(B, C, K, g, D).astype(jnp.float32)
     s = jnp.einsum("bckgd,btkd->bckgt", qf, k) * scale
     t = jnp.arange(T)[None, None, :]
@@ -138,6 +149,7 @@ def _flash_decode_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
         rest = rest[2:]
     m_ref, l_ref, acc_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
+    h = pl.program_id(1)
     si = pl.program_id(2)
     j = pl.program_id(3)
 
@@ -151,17 +163,24 @@ def _flash_decode_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
     page = si * pages_per_split + j
     live = _page_live(page, block_size, seq_len, window=window, chunk=chunk)
 
+    def head_row(ref):
+        """(1, bs) scales of kv head h from the page's (K, bs) block — a
+        masked sublane sum, so no dynamic sublane slice is needed."""
+        blk = ref[0]
+        rows = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 0)
+        return jnp.sum(jnp.where(rows == h, blk, 0.0), axis=0,
+                       keepdims=True)
+
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)             # (C*g, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)       # (bs, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)       # (bs, Dv)
-        if quantized:
-            k = k * ks_ref[0, :, 0][:, None]
-            v = v * vs_ref[0, :, 0][:, None]
+        k = k_ref[0, 0].astype(jnp.float32)             # (bs, D)
+        v = v_ref[0, 0].astype(jnp.float32)             # (bs, Dv)
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
+        if quantized:
+            s = s * head_row(ks_ref)                    # column t · ks[t]
         t = page * block_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         # per-row valid length: row r belongs to chunk index r // group
@@ -177,14 +196,15 @@ def _flash_decode_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
+        pv = p * head_row(vs_ref) if quantized else p    # (p · vs) @ v
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
+            pv, v, preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
     @pl.when(j == pages_per_split - 1)
     def _done():
-        m_ref[0, 0, 0] = m_scr[...][:, 0]
-        l_ref[0, 0, 0] = l_scr[...][:, 0]
+        m_ref[0, 0, 0] = jnp.broadcast_to(m_scr[...], m_ref.shape[3:])
+        l_ref[0, 0, 0] = jnp.broadcast_to(l_scr[...], l_ref.shape[3:])
         acc_ref[0, 0, 0] = acc_scr[...]
 
 
@@ -198,7 +218,7 @@ def _decode_bkgd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     chunk-major.  Returns the per-split partials (m, l, acc) of shapes
     (B,K,S,CG) / (B,K,S,CG) / (B,K,S,CG,Dv)."""
     B, K, CG, D = q.shape
-    bs = k_pages.shape[1]
+    bs = k_pages.shape[2]
     Dv = v_pages.shape[-1]
     nb = block_tables.shape[1]
     pps = min(pages_per_split, nb)
@@ -218,34 +238,32 @@ def _decode_bkgd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     in_specs = [
         pl.BlockSpec((1, 1, CG, D),
                      lambda b, h, si, j, bt, sl: (b, h, 0, 0)),
-        pl.BlockSpec((1, bs, 1, D),
+        pl.BlockSpec((1, 1, bs, D),
                      lambda b, h, si, j, bt, sl:
-                     (page_of(si, j, bt, b), 0, h, 0)),
-        pl.BlockSpec((1, bs, 1, Dv),
+                     (page_of(si, j, bt, b), h, 0, 0)),
+        pl.BlockSpec((1, 1, bs, Dv),
                      lambda b, h, si, j, bt, sl:
-                     (page_of(si, j, bt, b), 0, h, 0)),
+                     (page_of(si, j, bt, b), h, 0, 0)),
     ]
     operands = [q, k_pages, v_pages]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, bs, 1),
-                         lambda b, h, si, j, bt, sl:
-                         (page_of(si, j, bt, b), 0, h)),
-            pl.BlockSpec((1, bs, 1),
-                         lambda b, h, si, j, bt, sl:
-                         (page_of(si, j, bt, b), 0, h)),
-        ]
+        # all K heads' scales of the page: a (1, bs) head row would be a
+        # 1-wide second-minor block
+        scale_spec = pl.BlockSpec(
+            (1, K, bs),
+            lambda b, h, si, j, bt, sl: (page_of(si, j, bt, b), 0, 0))
+        in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
 
+    row_spec = pl.BlockSpec((1, 1, 1, CG, LANES),
+                            lambda b, h, si, j, bt, sl: (b, h, si, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, 1, CG),
-                         lambda b, h, si, j, bt, sl: (b, h, si, 0)),
-            pl.BlockSpec((1, 1, 1, CG),
-                         lambda b, h, si, j, bt, sl: (b, h, si, 0)),
+            row_spec,
+            row_spec,
             pl.BlockSpec((1, 1, 1, CG, Dv),
                          lambda b, h, si, j, bt, sl: (b, h, si, 0, 0)),
         ],
@@ -259,13 +277,13 @@ def _decode_bkgd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, K, num_splits, CG), jnp.float32),
-            jax.ShapeDtypeStruct((B, K, num_splits, CG), jnp.float32),
+            jax.ShapeDtypeStruct((B, K, num_splits, CG, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, K, num_splits, CG, LANES), jnp.float32),
             jax.ShapeDtypeStruct((B, K, num_splits, CG, Dv), jnp.float32),
         ],
         interpret=interpret,
     )(block_tables, seq_lens, *operands)
-    return m, l, acc
+    return m[..., 0], l[..., 0], acc
 
 
 @functools.partial(
@@ -279,16 +297,16 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                        k_scale: Optional[jax.Array] = None,
                        v_scale: Optional[jax.Array] = None) -> jax.Array:
     """Flash-decoding over paged KV.  q: (B, H, D), or (B, C, H, D) for a
-    teacher-forced prefill chunk; pages: (P, bs, K, D*); block_tables:
+    teacher-forced prefill chunk; pages: (P, K, bs, D*); block_tables:
     (B, NB) int32 page ids; seq_lens: (B,) int32 valid positions including
     the first query row's token (row ``c`` attends to ``seq_lens + c``).
-    ``k_scale``/``v_scale`` ((P, bs, K) fp32) dequantize int8 pages in
+    ``k_scale``/``v_scale`` ((P, K, bs) fp32) dequantize int8 pages in
     registers.  Returns q's shape with D -> Dv."""
     squeeze = q.ndim == 3
     if squeeze:
         q = q[:, None]
     B, C, H, D = q.shape
-    K = k_pages.shape[2]
+    K = k_pages.shape[1]
     Dv = v_pages.shape[-1]
     g = H // K
     if scale is None:

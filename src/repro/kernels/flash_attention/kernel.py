@@ -42,6 +42,11 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
+# Per-row statistics (logsumexp, Δ) cross HBM as (..., S, LANES) arrays,
+# the row's value broadcast over one lane tile: a TPU block's last two
+# dims must be (8k, 128k) or whole, so a (1, 1, block_q) row block does
+# not lower.  Residuals keep only lane 0.
+LANES = 128
 
 
 def _block_live(qi, ki, *, causal: bool, window: int, block_q: int,
@@ -129,7 +134,8 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     def _done():
         l = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_scr[...] + jnp.log(l))[:, 0]
+        lse_ref[0, 0] = jnp.broadcast_to(m_scr[...] + jnp.log(l),
+                                         lse_ref.shape[2:])
 
 
 def _fwd_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
@@ -168,11 +174,12 @@ def _fwd_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
         out_specs=[
             pl.BlockSpec((1, 1, block_q, Dv),
                          lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, qi, ki: (b, h, qi)),
+            pl.BlockSpec((1, 1, block_q, LANES),
+                         lambda b, h, qi, ki: (b, h, qi, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sp, Dv), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sp), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sp, LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -181,7 +188,7 @@ def _fwd_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
         ],
         interpret=interpret,
     )(q, k, v)
-    return out[:, :, :S, :], lse[:, :, :S]
+    return out[:, :, :S, :], lse[:, :, :S, 0]
 
 
 # --------------------------------------------------------------------------- #
@@ -210,8 +217,8 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0, 0].astype(jnp.float32)            # (bk, d)
         v = v_ref[0, 0].astype(jnp.float32)            # (bk, dv)
         do = do_ref[0, 0].astype(jnp.float32)          # (bq, dv)
-        lse = lse_ref[0, 0][:, None]                   # (bq, 1)
-        dlt = delta_ref[0, 0][:, None]                 # (bq, 1)
+        lse = lse_ref[0, 0][:, :1]                     # (bq, 1)
+        dlt = delta_ref[0, 0][:, :1]                   # (bq, 1)
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
@@ -254,8 +261,8 @@ def _fa_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0, 0].astype(jnp.float32)            # (bk, d)
         v = v_ref[0, 0].astype(jnp.float32)            # (bk, dv)
         do = do_ref[0, 0].astype(jnp.float32)          # (bq, dv)
-        lse = lse_ref[0, 0][:, None]                   # (bq, 1)
-        dlt = delta_ref[0, 0][:, None]                 # (bq, 1)
+        lse = lse_ref[0, 0][:, :1]                     # (bq, 1)
+        dlt = delta_ref[0, 0][:, :1]                   # (bq, 1)
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
@@ -307,12 +314,15 @@ def _bwd_bhsd(q, k, v, out, lse, do, causal: bool, window: int,
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
     Sp, Tp = S + pad_q, T + pad_k
     nq, nk = Sp // block_q, Tp // block_k
+    lse = jnp.broadcast_to(lse[..., None], (B, H, Sp, LANES))
+    delta = jnp.broadcast_to(delta[..., None], (B, H, Sp, LANES))
 
     q_spec = pl.BlockSpec((1, 1, block_q, D),
                           lambda b, h, i, j: (b, h, i, 0))
     do_spec = pl.BlockSpec((1, 1, block_q, Dv),
                            lambda b, h, i, j: (b, h, i, 0))
-    row_spec = pl.BlockSpec((1, 1, block_q), lambda b, h, i, j: (b, h, i))
+    row_spec = pl.BlockSpec((1, 1, block_q, LANES),
+                            lambda b, h, i, j: (b, h, i, 0))
     k_spec = pl.BlockSpec((1, 1, block_k, D),
                           lambda b, h, i, j, g=group: (b, h // g, j, 0))
     v_spec = pl.BlockSpec((1, 1, block_k, Dv),
@@ -339,7 +349,8 @@ def _bwd_bhsd(q, k, v, out, lse, do, causal: bool, window: int,
                            lambda b, h, j, i: (b, h, i, 0))
     dok_spec = pl.BlockSpec((1, 1, block_q, Dv),
                             lambda b, h, j, i: (b, h, i, 0))
-    rowk_spec = pl.BlockSpec((1, 1, block_q), lambda b, h, j, i: (b, h, i))
+    rowk_spec = pl.BlockSpec((1, 1, block_q, LANES),
+                             lambda b, h, j, i: (b, h, i, 0))
     kk_spec = pl.BlockSpec((1, 1, block_k, D),
                            lambda b, h, j, i, g=group: (b, h // g, j, 0))
     vk_spec = pl.BlockSpec((1, 1, block_k, Dv),

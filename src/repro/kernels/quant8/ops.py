@@ -1,4 +1,10 @@
-"""Public wrappers: quantize/dequantize arbitrary-shape arrays (pads tail)."""
+"""Public wrappers: quantize/dequantize arbitrary-shape arrays.
+
+The flat input is zero-padded to whole kernel tiles (``block`` elements
+per row, ``ROW_TILE`` rows per grid step), so the Pallas kernel runs for
+every shape; padded rows quantize to zeros with scale 1 and are sliced
+off by :func:`dequantize`.
+"""
 
 from __future__ import annotations
 
@@ -6,13 +12,21 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.kernels.quant8.kernel import dequantize_blocks, quantize_blocks
-from repro.kernels.quant8 import ref as qref
+from repro.kernels.quant8.kernel import (ROW_TILE, dequantize_blocks,
+                                         quantize_blocks)
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _padded_size(n: int, block: int) -> int:
+    """Elements after padding ``n`` to whole rows and whole row tiles."""
+    rows = -(-n // block)
+    tile = min(ROW_TILE, rows)
+    return -(-rows // tile) * tile * block
 
 
 def quantize(x: jax.Array, block: int = 256, *, interpret=None
@@ -22,14 +36,10 @@ def quantize(x: jax.Array, block: int = 256, *, interpret=None
         interpret = not _on_tpu()
     shape = x.shape
     flat = x.reshape(-1)
-    pad = (-flat.shape[0]) % block
+    pad = _padded_size(flat.shape[0], block) - flat.shape[0]
     if pad:
         flat = jnp.pad(flat, (0, pad))
-    rows = flat.shape[0] // block
-    if rows % min(256, rows):   # irregular row count: fall back to the oracle
-        q, s = qref.quantize_reference(flat, block)
-    else:
-        q, s = quantize_blocks(flat, block=block, interpret=interpret)
+    q, s = quantize_blocks(flat, block=block, interpret=interpret)
     return q, s, shape
 
 
@@ -38,13 +48,8 @@ def dequantize(q: jax.Array, scale: jax.Array, shape: Tuple[int, ...],
                ) -> jax.Array:
     if interpret is None:
         interpret = not _on_tpu()
-    rows = q.shape[0] // block
-    if rows % min(256, rows):
-        flat = qref.dequantize_reference(q, scale, block, dtype)
-    else:
-        flat = dequantize_blocks(q, scale, block=block, dtype=dtype,
-                                 interpret=interpret)
-    import numpy as np
+    flat = dequantize_blocks(q, scale, block=block, dtype=dtype,
+                             interpret=interpret)
     n = int(np.prod(shape))
     return flat[:n].reshape(shape)
 

@@ -73,7 +73,6 @@ def main():
 
     # reuse dryrun's lowering, but grab the HLO text
     import jax
-    from repro import compat
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs.registry import get_config, input_shape
     from repro.launch.mesh import make_production_mesh
@@ -89,7 +88,7 @@ def main():
     mesh = make_production_mesh(multi_pod=args.multi_pod)
     p_abs = PM.abstract_params(cfg)
     p_shard = SH.param_shardings(cfg, mesh, SH.DEFAULT_RULES)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt_cfg = adamw.OptConfig(moment_dtype=args.moment_dtype)
             opt_abs = jax.eval_shape(

@@ -19,7 +19,6 @@ import jax            # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.configs.registry import (INPUT_SHAPES, get_config, input_shape,  # noqa: E402
                                     list_archs, shape_applicable)
 from repro.launch.hlo_analysis import collective_totals, compute_totals  # noqa: E402
@@ -90,7 +89,7 @@ def lower_case(arch: str, shape_name: str, *, multi_pod: bool = False,
     p_abs = PM.abstract_params(cfg)
     p_shard = SH.param_shardings(cfg, mesh, rules)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return _lower_compile_record(cfg, shape, mesh, rules, arch,
                                      shape_name, multi_pod, remat,
                                      moment_dtype, rules_name, donate,

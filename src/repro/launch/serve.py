@@ -71,10 +71,12 @@ def main() -> None:
 
     import jax
     from repro.configs import get_config
+    from repro.launch.compile_cache import use_compile_cache
     from repro.core.energy.devices import get_device
     from repro.models import model as M
     from repro.models import params as P
 
+    use_compile_cache()
     device = get_device(args.device)
     cfg = get_config(args.arch if args.full else args.arch + "-smoke")
     print(f"[serve] {cfg.name}: {cfg.param_count()/1e6:.1f}M params "

@@ -107,14 +107,16 @@ def main() -> None:
             + os.environ.get("XLA_FLAGS", ""))
 
     import jax
-    from repro import compat
     from repro.configs import get_config
+    from repro.launch.compile_cache import use_compile_cache
     from repro.core.carbon.accounting import CarbonLedger
     from repro.core.energy.devices import get_device
     from repro.core.energy.monitor import ComponentModel, EnergyMonitor
+    from repro.launch.mesh import make_mesh
     from repro.obs import MetricsRegistry, Tracer, set_tracer
     from repro.train.trainer import TrainerConfig, train
 
+    use_compile_cache()
     cfg = get_config(args.arch if args.full else args.arch + "-smoke")
     print(f"[train] {cfg.name}: {cfg.param_count()/1e6:.1f}M params on "
           f"{jax.device_count()} device(s)")
@@ -179,8 +181,8 @@ def main() -> None:
 
     if args.mesh:
         dims = tuple(int(x) for x in args.mesh.split(","))
-        mesh = jax.make_mesh(dims, ("data", "model")[: len(dims)])
-        with compat.set_mesh(mesh):
+        mesh = make_mesh(dims, ("data", "model")[: len(dims)])
+        with jax.set_mesh(mesh):
             res = _run()
     else:
         res = _run()

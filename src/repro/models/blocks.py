@@ -166,16 +166,19 @@ def init_paged_sublayer_cache(kind: str, cfg: ModelConfig, num_blocks: int,
     dim — sequences share the pool through their block tables.  With
     ``dtype`` int8 the pools are quantized per (page slot, kv head)
     vector and carry fp32 ``k_scale``/``v_scale`` pools alongside —
-    (head_dim + 4) / (2 * head_dim) of the bf16 KV bytes per block."""
+    (head_dim + 4) / (2 * head_dim) of the bf16 KV bytes per block.
+    Pages are head-major, (blocks, kv_heads, block_size, head_dim): the
+    layout the flash-decode kernel tiles (``kernels/flash_attention/
+    decode.py``)."""
     if kind == "attn":
         K, hd = cfg.num_kv_heads, cfg.resolved_head_dim
         dt = jnp.dtype(dtype)
-        pools = {"k_pages": jnp.zeros((num_blocks, block_size, K, hd), dt),
-                 "v_pages": jnp.zeros((num_blocks, block_size, K, hd), dt)}
+        pools = {"k_pages": jnp.zeros((num_blocks, K, block_size, hd), dt),
+                 "v_pages": jnp.zeros((num_blocks, K, block_size, hd), dt)}
         if dt == jnp.int8:
-            pools["k_scale"] = jnp.ones((num_blocks, block_size, K),
+            pools["k_scale"] = jnp.ones((num_blocks, K, block_size),
                                         jnp.float32)
-            pools["v_scale"] = jnp.ones((num_blocks, block_size, K),
+            pools["v_scale"] = jnp.ones((num_blocks, K, block_size),
                                         jnp.float32)
         return pools
     if kind in ("mlp", "moe"):

@@ -278,7 +278,7 @@ def attn_decode_paged(p: Dict[str, Any], x: jax.Array, cfg: ModelConfig, *,
     — C teacher-forced rows per sequence (C == 1 is plain decode).
 
     ``cache`` holds the shared block pools: ``k_pages``/``v_pages`` of
-    shape (P, bs, K, D), plus ``k_scale``/``v_scale`` ((P, bs, K) fp32)
+    shape (P, K, bs, D), plus ``k_scale``/``v_scale`` ((P, K, bs) fp32)
     when the pools are int8 (per-vector quant via ``kernels.quant8``,
     applied at append time here and inverted inside the attention
     gather).  block_tables: (B, NB) int32 page ids; seq_lens: (B,) cache
@@ -298,7 +298,7 @@ def attn_decode_paged(p: Dict[str, Any], x: jax.Array, cfg: ModelConfig, *,
     k_scale = cache.get("k_scale")
     v_scale = cache.get("v_scale")
     B, C, _ = x.shape
-    bs = k_pages.shape[1]
+    bs = k_pages.shape[2]
     nb = block_tables.shape[1]
     h = norm(p["norm"], x, cfg)
     q, k, v = _project_qkv(p, h, cfg)
@@ -318,13 +318,13 @@ def attn_decode_paged(p: Dict[str, Any], x: jax.Array, cfg: ModelConfig, *,
     if k_scale is not None:                    # int8 pools: quantize at append
         kq, ks = quantize_kv(k)
         vq, vs = quantize_kv(v)
-        k_pages = k_pages.at[page_ids, offs].set(kq)
-        v_pages = v_pages.at[page_ids, offs].set(vq)
-        k_scale = k_scale.at[page_ids, offs].set(ks)
-        v_scale = v_scale.at[page_ids, offs].set(vs)
+        k_pages = k_pages.at[page_ids, :, offs].set(kq)
+        v_pages = v_pages.at[page_ids, :, offs].set(vq)
+        k_scale = k_scale.at[page_ids, :, offs].set(ks)
+        v_scale = v_scale.at[page_ids, :, offs].set(vs)
     else:
-        k_pages = k_pages.at[page_ids, offs].set(k.astype(k_pages.dtype))
-        v_pages = v_pages.at[page_ids, offs].set(v.astype(v_pages.dtype))
+        k_pages = k_pages.at[page_ids, :, offs].set(k.astype(k_pages.dtype))
+        v_pages = v_pages.at[page_ids, :, offs].set(v.astype(v_pages.dtype))
     valid = seq_lens + 1                 # incl. the first row just written
     window = cfg.sliding_window
     qf = q.astype(jnp.float32)

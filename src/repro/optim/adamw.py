@@ -44,7 +44,9 @@ def schedule(cfg: OptConfig, step: jax.Array) -> jax.Array:
 
 def init_opt_state(params: PyTree, cfg: OptConfig) -> Dict[str, Any]:
     dt = jnp.dtype(cfg.moment_dtype)
-    zeros = lambda p: jnp.zeros(p.shape, dt)
+    # zeros_like keeps each param's sharding: moments live where their
+    # params live (jnp.zeros would put them all on the default device)
+    zeros = lambda p: jnp.zeros_like(p, dtype=dt)
     return {"mu": jax.tree.map(zeros, params),
             "nu": jax.tree.map(zeros, params),
             "step": jnp.zeros((), jnp.int32)}

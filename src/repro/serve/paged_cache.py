@@ -9,7 +9,7 @@ The serving engine instead carves each layer's KV storage into fixed-size
 token positions to physical blocks through a per-sequence **block table**:
 
 * device side — per-attention-layer pools ``k_pages``/``v_pages`` of shape
-  ``(num_blocks, block_size, kv_heads, head_dim)`` (see
+  ``(num_blocks, kv_heads, block_size, head_dim)`` (see
   ``model.init_paged_cache``); block ids are shared across layers, so one
   table drives every layer's gather,
 * host side — this module: a refcounting :class:`BlockAllocator` plus

@@ -18,17 +18,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.distributed.act_sharding import (BATCH, MODEL, axis_extent,
                                             constrain)
+from repro.launch.mesh import make_mesh
 
 
 def _mesh():
-    return jax.make_mesh((2, 4), ("data", "model"))
+    return make_mesh((2, 4), ("data", "model"))
 
 
 def _lowered_constraints(fn, *args):
-    with compat.set_mesh(_mesh()):
+    with jax.set_mesh(_mesh()):
         txt = jax.jit(fn).lower(*args).as_text()
     return [ln for ln in txt.splitlines()
             if "sharding_constraint" in ln or "mhlo.sharding" in ln]
@@ -39,11 +39,7 @@ def test_constraint_reaches_ir():
     lines = _lowered_constraints(
         lambda x: constrain(x, BATCH, MODEL, None).sum(), x)
     assert lines, "constrain() lowered to nothing (AxisType regression)"
-    if compat.SHARDY_IR:
-        assert any("data" in ln and "model" in ln for ln in lines)
-    else:
-        # GSPMD IR (jax 0.4.x): device-list form of the same (2, 4, 1) split
-        assert any("devices=[2,4,1]" in ln for ln in lines), lines
+    assert any("data" in ln and "model" in ln for ln in lines)
 
 
 def test_priority_picks_first_dividing_dim():
@@ -53,19 +49,13 @@ def test_priority_picks_first_dividing_dim():
     lines = _lowered_constraints(
         lambda x: constrain(x, BATCH, MODEL, MODEL, MODEL).sum(), x)
     assert lines
-    if compat.SHARDY_IR:
-        (ln,) = [l for l in lines if "sharding_constraint" in l]
-        # dim1 unconstrained, dim2 model
-        assert '{"data"}, {?}, {"model"}, {?}' in ln, ln
-    else:
-        # GSPMD: dims 1/3 unspecified, dim0 data(2), dim2 model(4)
-        (ln,) = [l for l in lines if "mhlo.sharding" in l]
-        assert "devices=[2,1,4,1]" in ln, ln
-        assert "unspecified_dims=[1,3]" in ln, ln
+    (ln,) = [l for l in lines if "sharding_constraint" in l]
+    # dim1 unconstrained, dim2 model
+    assert '{"data"}, {?}, {"model"}, {?}' in ln, ln
 
 
 def test_axis_extent():
-    with compat.set_mesh(_mesh()):
+    with jax.set_mesh(_mesh()):
         def f(x):
             assert axis_extent("model") == 4
             assert axis_extent("data") == 2
@@ -83,7 +73,7 @@ def test_sharded_matches_unsharded_numerics():
     k = jax.random.normal(jax.random.PRNGKey(1), (B, S, K, D), jnp.float32)
     v = jax.random.normal(jax.random.PRNGKey(2), (B, S, K, D), jnp.float32)
     ref = chunked_attention(q, k, v, causal=True, chunk=64)
-    with compat.set_mesh(_mesh()):
+    with jax.set_mesh(_mesh()):
         out = jax.jit(lambda q, k, v: chunked_attention(
             q, k, v, causal=True, chunk=64))(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -96,13 +86,13 @@ def test_gqa_kv_expand_matches_grouped():
     from repro.kernels.flash_attention.chunked import chunked_attention
     # H=8 divides model extent 4; K=2 and g=4 both... g=4 divides; pick
     # H=8, K=2, g=4 on extent 8? Use mesh (1, 8): H=8%8==0, K=2%8!=0, g=4%8!=0
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_mesh((1, 8), ("data", "model"))
     B, S, H, K, D = 2, 128, 8, 2, 16
     q = jax.random.normal(jax.random.PRNGKey(0), (B, S, H, D), jnp.float32)
     k = jax.random.normal(jax.random.PRNGKey(1), (B, S, K, D), jnp.float32)
     v = jax.random.normal(jax.random.PRNGKey(2), (B, S, K, D), jnp.float32)
     ref = chunked_attention(q, k, v, causal=True, chunk=32)   # no mesh
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(lambda q, k, v: chunked_attention(
             q, k, v, causal=True, chunk=32))(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),

@@ -9,9 +9,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro import compat
 from repro.launch.hlo_analysis import (collective_totals, compute_totals,
                                        loop_trip_counts)
+from repro.launch.mesh import make_mesh
 
 
 def test_scan_flops_exact():
@@ -47,7 +47,7 @@ def test_nested_scan_multiplies():
 
 
 def test_collectives_counted_per_device_with_trips():
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     def f(x):
@@ -61,7 +61,7 @@ def test_collectives_counted_per_device_with_trips():
         return h.sum()
 
     x = jax.ShapeDtypeStruct((64, 32), jnp.float32)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         hlo = jax.jit(
             f, in_shardings=NamedSharding(mesh, P("data"))
         ).lower(x).compile().as_text()
@@ -85,7 +85,7 @@ def test_train_step_lowers_on_local_mesh_and_parses():
 
     cfg = get_config("granite-3-2b").reduced(num_layers=2, d_model=256,
                                              vocab_size=512)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     p_abs = PM.abstract_params(cfg)
     p_shard = SH.param_shardings(cfg, mesh, SH.DEFAULT_RULES)
     opt_cfg = adamw.OptConfig()
@@ -98,7 +98,7 @@ def test_train_step_lowers_on_local_mesh_and_parses():
              "labels": jax.ShapeDtypeStruct((B, S), jnp.int32)}
     b_shard = SH.batch_shardings(mesh, batch)
     step = make_train_step(cfg, opt_cfg, remat="full", microbatches=2)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(step, in_shardings=(p_shard, opt_shard, b_shard),
                            out_shardings=(p_shard, opt_shard, None)
                            ).lower(p_abs, opt_abs, batch).compile()

@@ -200,3 +200,26 @@ def test_quant8_roundtrip_error_bound(shape):
                       256)[:flat.shape[0]]
     err = np.abs(np.asarray(xr, np.float32).reshape(-1) - flat)
     assert np.all(err <= bound)
+
+
+@pytest.mark.parametrize("shape", [(100, 777), (3, 5, 7), (300, 256)])
+def test_quant8_irregular_rows_run_the_kernel(shape):
+    """Row counts that are not a multiple of the row tile (304, 1 and 300
+    rows of 256) are padded to whole tiles: the Pallas kernel runs, its
+    codes on the real rows equal the oracle's, and its scales agree to
+    the ULP by which XLA's amax * (1/127) may differ from amax / 127."""
+    x = jax.random.normal(jax.random.PRNGKey(6), shape) * 2
+    assert "pallas_call" in str(jax.make_jaxpr(quantize)(x))
+    q, s, sh = quantize(x)
+    n = x.size
+    rows = -(-n // 256)
+    flat = jnp.pad(x.reshape(-1), (0, rows * 256 - n))
+    qr, sr = quantize_reference(flat, 256)
+    assert bool(jnp.all(q[:rows * 256] == qr))
+    np.testing.assert_allclose(np.asarray(s[:rows]), np.asarray(sr),
+                               rtol=1e-6)
+    assert not np.asarray(q[rows * 256:]).any()
+    np.testing.assert_allclose(
+        np.asarray(dequantize(q, s, sh)),
+        np.asarray(dequantize_reference(qr, sr, 256)[:n].reshape(shape)),
+        rtol=1e-6)

@@ -198,8 +198,12 @@ def test_local_sgd_decreases_loss_quickstart_size():
     res = train_local_sgd(
         cfg, TrainerConfig(steps=steps, batch=4, seq_len=64, log_every=0,
                            seed=0),
+        # outer momentum 0.5, not DiLoCo's 0.9: that value is tuned for
+        # K ~ 500; at K=4 it builds a ~7x outer step and the loss climbs
+        # again after round 2, so the test passed only on init draws that
+        # left round 0 high
         LocalSGDConfig(replicas=2, inner_steps=4, outer_lr=0.7,
-                       outer_momentum=0.9,
+                       outer_momentum=0.5,
                        compress=CompressConfig(method="int8")),
         adamw.OptConfig(learning_rate=3e-3, warmup_steps=2,
                         decay_steps=steps),

@@ -16,12 +16,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.configs.opt import opt_config
 from repro.data.pipeline import make_batch_fn
 from repro.distributed.pipeline import (make_pipeline_loss,
                                         pipeline_train_step,
                                         stack_for_stages, unstack_stages)
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.models import params as P
 from repro.optim import adamw
@@ -37,7 +37,7 @@ def _tiny_cfg():
 def test_pipeline_loss_matches_plain_forward():
     """GPipe schedule over 2 stages == unpipelined forward loss."""
     cfg = _tiny_cfg()
-    mesh = jax.make_mesh((1, 2), ("data", "stage"))
+    mesh = make_mesh((1, 2), ("data", "stage"))
     params = P.init_params(cfg, jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
                               cfg.vocab_size)
@@ -47,7 +47,7 @@ def test_pipeline_loss_matches_plain_forward():
 
     loss_fn = make_pipeline_loss(cfg, mesh, num_microbatches=2)
     staged = stack_for_stages(cfg, params, 2)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         pipe_loss = jax.jit(loss_fn)(params, staged, batch)
     np.testing.assert_allclose(float(pipe_loss), float(ref_loss),
                                rtol=5e-3)
@@ -55,12 +55,12 @@ def test_pipeline_loss_matches_plain_forward():
 
 def test_pipeline_trains():
     cfg = _tiny_cfg()
-    mesh = jax.make_mesh((2, 2), ("data", "stage"))
+    mesh = make_mesh((2, 2), ("data", "stage"))
     opt_cfg = adamw.OptConfig(learning_rate=1e-3, warmup_steps=5,
                               decay_steps=40)
     init_fn, step_fn = pipeline_train_step(cfg, mesh, opt_cfg,
                                            num_microbatches=2)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         rest, staged, opt = init_fn(jax.random.PRNGKey(0))
         data = make_batch_fn(cfg, 4, 32, seed=0)
         losses = []

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.configs.opt import opt_config
 from repro.core.energy.devices import (CATALOG, CLOUD_A5000, LAPTOP_M2PRO,
                                        SMARTPHONE_SD888)
@@ -36,6 +35,7 @@ from repro.core.planner import dtfm
 from repro.core.sched.carbon_aware import FleetDevice
 from repro.distributed.pipeline import (make_pipeline_loss, stack_for_stages,
                                         stage_layer_mask, unstack_stages)
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.models import params as P
 
@@ -124,7 +124,7 @@ def test_nonuniform_pipeline_matches_unpipelined_loss_and_grads():
     plain forward to fp32 tolerance — masked padding is inert."""
     cfg = _cfg8()
     boundaries = [0, 3, 6, 8]
-    mesh = jax.make_mesh((1, 3), ("data", "stage"))
+    mesh = make_mesh((1, 3), ("data", "stage"))
     params = P.init_params(cfg, jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
                               cfg.vocab_size)
@@ -142,7 +142,7 @@ def test_nonuniform_pipeline_matches_unpipelined_loss_and_grads():
     def pipe_loss(p, st):
         return loss_fn(p, st, batch)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         pipe, (g_rest, g_staged) = jax.jit(
             jax.value_and_grad(pipe_loss, argnums=(0, 1)))(params, staged)
 
