@@ -12,7 +12,7 @@ the DiLoCo-style local-update loop (``--replicas`` × ``--inner-steps``);
 seed-replayable fault plan (``--fault-seed``).
 
 Telemetry: ``--trace-out trace.json`` captures a Chrome-trace /
-Perfetto timeline of every step phase (data / fwd_bwd_opt / outer-sync /
+Perfetto timeline of every step phase (data / dispatch / outer-sync /
 checkpoint, with J + gCO2e attached); ``--metrics-out metrics.jsonl``
 writes the metrics registry (per-phase step-time histograms with
 p50/p95/p99, loss/grad-norm distributions, byte counters).  Validate

@@ -168,6 +168,7 @@ def _project_qkv(p: Dict[str, Any], x: jax.Array, cfg: ModelConfig,
 ATTN_IMPLS = ("naive", "chunked", "pallas")
 
 
+@jax.named_scope("attention_core")
 def run_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   causal: bool, window: int = 0, impl: str = "chunked",
                   scale: Optional[float] = None) -> jax.Array:
@@ -175,6 +176,8 @@ def run_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     All three are differentiable — "pallas" carries a fused FA-2 backward
     (interpret mode off-TPU), so every impl is a valid training path.
+    Whatever implements it, its ops carry the ``attention_core`` scope in
+    their HLO ``op_name`` (backward ops under a ``transpose(...)``).
     """
     if impl == "naive":
         return attention_core(q, k, v, causal=causal, window=window)
@@ -190,6 +193,7 @@ def run_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                              scale=scale)
 
 
+@jax.named_scope("attention")
 def attn_forward(p: Dict[str, Any], x: jax.Array, cfg: ModelConfig, *,
                  positions: jax.Array, causal: bool = True,
                  impl: str = "chunked") -> jax.Array:
@@ -347,6 +351,7 @@ def attn_decode_paged(p: Dict[str, Any], x: jax.Array, cfg: ModelConfig, *,
 # MLP
 # --------------------------------------------------------------------------- #
 
+@jax.named_scope("mlp")
 def mlp_forward(p: Dict[str, Any], x: jax.Array, cfg: ModelConfig) -> jax.Array:
     h = norm(p["norm"], x, cfg)
     wi = p["wi"].astype(x.dtype)

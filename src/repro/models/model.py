@@ -153,6 +153,14 @@ def forward_train(params: PyTree, cfg: ModelConfig,
     "naive" (fp32 oracle), "chunked" (XLA flash twin, default), or "pallas"
     (fused TPU kernel with the FA-2 custom-VJP backward; interpret mode on
     CPU).  All three train — gradients flow through each impl.
+
+    The step's device work is named by ``jax.named_scope``: ``embed``,
+    ``attention`` (with ``attention_core`` inside), ``mlp`` and ``lm_head``
+    here and in ``layers``, ``optimizer`` in ``train.step``.  The names
+    reach each compiled instruction's ``op_name`` as a path component,
+    bare or wrapped in ``jvp(...)``, backward ops under a
+    ``transpose(...)`` component, so a device trace can be split by layer
+    whatever implements it.
     """
     from repro.distributed.act_sharding import BATCH, constrain
     tokens = batch["tokens"]
@@ -162,18 +170,21 @@ def forward_train(params: PyTree, cfg: ModelConfig,
     if cfg.is_encoder_decoder:
         enc = encoder_forward(params, cfg, batch["frames"],
                               {"remat": remat, "attn_impl": attn_impl})
-        x = embed_tokens(params, cfg, tokens)
-        if "pos" in params["embed"]:
-            S = tokens.shape[1]
-            x = x + params["embed"]["pos"][:S].astype(x.dtype)[None]
+        with jax.named_scope("embed"):
+            x = embed_tokens(params, cfg, tokens)
+            if "pos" in params["embed"]:
+                S = tokens.shape[1]
+                x = x + params["embed"]["pos"][:S].astype(x.dtype)[None]
         S = tokens.shape[1]
         ctx = {"positions": default_positions(batch, cfg, S, B),
                "remat": remat, "attn_impl": attn_impl, "causal": True,
                "enc": enc}
     else:
-        x = embed_tokens(params, cfg, tokens)
-        if "pos" in params["embed"]:
-            x = x + params["embed"]["pos"][:tokens.shape[1]].astype(x.dtype)[None]
+        with jax.named_scope("embed"):
+            x = embed_tokens(params, cfg, tokens)
+            if "pos" in params["embed"]:
+                x = x + params["embed"]["pos"][:tokens.shape[1]] \
+                    .astype(x.dtype)[None]
         if "vision_embeds" in batch:
             v = batch["vision_embeds"].astype(x.dtype)
             x = jnp.concatenate([v, x], axis=1)
@@ -183,9 +194,10 @@ def forward_train(params: PyTree, cfg: ModelConfig,
 
     x = constrain(x, BATCH, None, None)
     h, aux = decoder_trunk(params, cfg, x, ctx)
-    logits = lm_logits(params, cfg, h)
-    logits = constrain(logits, BATCH, None, "model")
-    loss, n_tok = cross_entropy(logits, labels)
+    with jax.named_scope("lm_head"):
+        logits = lm_logits(params, cfg, h)
+        logits = constrain(logits, BATCH, None, "model")
+        loss, n_tok = cross_entropy(logits, labels)
     metrics = {"ce_loss": loss, "aux_loss": aux, "tokens": n_tok}
     total = loss + aux
     if cfg.mtp_depth > 0:

@@ -1,4 +1,5 @@
-"""Span tracing with Chrome-trace / Perfetto and JSONL export.
+"""Span tracing with Chrome-trace / Perfetto export, on the profiler's
+clock too.
 
 The fleet telemetry substrate (§5 generalized: time × bytes × joules ×
 gCO2e need ONE timeline to be comparable).  Design constraints, in order:
@@ -6,19 +7,24 @@ gCO2e need ONE timeline to be comparable).  Design constraints, in order:
 * **near-zero overhead when disabled** — the default global tracer is
   off; ``tracer.span(...)`` then returns a shared no-op context manager
   after a single attribute check, so the zero-sync training loops (PR 2)
-  keep their step time (gated by ``bench_train_step.py`` and the
-  tight-loop overhead test in ``tests/test_obs.py``);
+  keep their step time (held by the tight-loop overhead test in
+  ``tests/test_obs.py``);
 * **thread-safe** — events append under the GIL; span nesting lives in a
   ``threading.local`` stack so concurrent threads trace independently;
 * **monotonic timestamps** — ``time.perf_counter_ns`` relative to the
   tracer's epoch; wall clock never appears in a timeline;
-* **two export formats** — Chrome trace-event JSON (open in
-  https://ui.perfetto.dev or ``chrome://tracing``) and a line-per-event
-  JSONL log for ad-hoc grep/pandas analysis.
+* **one clock with the device** — while the tracer is enabled, every
+  ``span``/``begin`` span also opens a ``jax.profiler.TraceAnnotation``
+  named ``<cat>.<name>``, which a running ``jax.profiler`` session records
+  on the host plane beside the device's ops (and which costs a check
+  when no session runs).  ``complete``/``instant``/``counter`` may carry
+  simulated clocks and are never bridged;
+* **Chrome trace-event JSON export** (open in https://ui.perfetto.dev or
+  ``chrome://tracing``), read back by ``repro.obs.analyze``.
 
 Three span shapes cover every producer in the repo:
 
-* ``with tracer.span("fwd_bwd_opt", "train"):`` — stack-nested complete
+* ``with tracer.span("dispatch", "train"):`` — stack-nested complete
   events (trainer / local-SGD step phases).  ``metric="train/step_s"``
   additionally feeds the span's duration into the attached
   :class:`~repro.obs.metrics.MetricsRegistry` histogram on exit.
@@ -63,9 +69,24 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+_TraceAnnotation = None
+
+
+def _profiler_annotation(name: str):
+    """An open ``jax.profiler.TraceAnnotation`` (jax imported on first
+    use, so that a disabled tracer never imports it)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    ann = _TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
 class Span:
     __slots__ = ("_tr", "name", "cat", "tid", "metric", "args", "t0_us",
-                 "dur_us", "_open")
+                 "dur_us", "_open", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, cat: str, tid: int,
                  metric: Optional[str], args: Dict[str, Any]):
@@ -78,6 +99,7 @@ class Span:
         self.t0_us = tr._now_us()
         self.dur_us = 0.0
         self._open = True
+        self._ann = _profiler_annotation(f"{cat}.{name}" if cat else name)
 
     def set(self, **attrs) -> "Span":
         self.args.update(attrs)
@@ -106,6 +128,7 @@ class Span:
             return
         self._open = False
         self.dur_us = self._tr._now_us() - self.t0_us
+        self._ann.__exit__(None, None, None)
         self._tr._record("X", self.name, self.cat, self.t0_us,
                          self.dur_us, self.tid, self.args)
         if self._tr.registry is not None and self.metric:
@@ -254,18 +277,6 @@ class Tracer:
     def save_chrome_trace(self, path: str) -> None:
         with open(path, "w") as f:
             json.dump(self.to_chrome(), f)
-
-    def save_jsonl(self, path: str) -> None:
-        """One JSON object per line: ``{name, cat, ph, ts_us, dur_us,
-        track, args}`` — the grep/pandas-friendly event log."""
-        names = {tid: track for track, tid in self._tracks.items()}
-        with open(path, "w") as f:
-            for e in self._events:
-                f.write(json.dumps({
-                    "name": e["name"], "cat": e["cat"], "ph": e["ph"],
-                    "ts_us": e["ts"], "dur_us": e["dur"],
-                    "track": names.get(e["tid"], str(e["tid"])),
-                    "args": e["args"]}) + "\n")
 
 
 # ------------------------------------------------------------ global tracer

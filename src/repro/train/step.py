@@ -80,8 +80,9 @@ def make_train_step(cfg: ModelConfig, opt_cfg: adamw.OptConfig, *,
                 loss_fn, has_aux=True)(params, batch)
         if compress is not None and compress.method != "none":
             grads, _ = compress_grads(grads, None, compress)
-        params, opt_state, opt_metrics = adamw.apply_updates(
-            params, grads, opt_state, opt_cfg)
+        with jax.named_scope("optimizer"):
+            params, opt_state, opt_metrics = adamw.apply_updates(
+                params, grads, opt_state, opt_cfg)
         metrics = dict(metrics, loss=loss, **opt_metrics)
         return params, opt_state, metrics
 

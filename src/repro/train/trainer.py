@@ -39,6 +39,7 @@ from repro.checkpoint import ckpt
 from repro.core import flops as F
 from repro.core.energy.monitor import ComponentModel, EnergyMonitor
 from repro.data.pipeline import make_batch_fn
+from repro.obs import compiles
 from repro.obs.metrics import DeviceAccumulator, MetricsRegistry
 from repro.obs.trace import get_tracer
 from repro.models import params as PM
@@ -80,6 +81,8 @@ class TrainerResult:
     energy_wh: float = 0.0
     final_loss: float = float("nan")
     resumed_from_step: int = 0          # 0 when starting fresh
+    loop_compiles: int = 0              # JAX traces + compiles after the
+                                        # first step (``repro.obs.compiles``)
 
 
 def donation_supported() -> bool:
@@ -97,7 +100,12 @@ def effective_donate(tc: TrainerConfig) -> bool:
 def make_jit_train_step(cfg: ModelConfig, tc: TrainerConfig,
                         opt_cfg: adamw.OptConfig) -> Callable:
     """The trainer's jit: (params, opt_state) donated per
-    ``effective_donate`` — requested donation ∧ backend support."""
+    ``effective_donate`` — requested donation ∧ backend support.
+
+    Installs the process-wide compile counter (``repro.obs.compiles``),
+    so that whoever drives the step can tell whether it compiled again
+    inside its loop."""
+    compiles.install()
     return jax.jit(
         make_train_step(cfg, opt_cfg, remat=tc.remat,
                         attn_impl=tc.attn_impl,
@@ -156,6 +164,7 @@ def train(cfg: ModelConfig, tc: TrainerConfig,
     acc = DeviceAccumulator(metrics) if metrics is not None else None
 
     batch = jax.device_put(next(data)) if tc.prefetch else None
+    compiled_by_step0 = compiles.count()
     t0 = time.time()
     t_prev = t0
     for step in range(tc.steps):
@@ -168,8 +177,7 @@ def train(cfg: ModelConfig, tc: TrainerConfig,
         # forward+backward+optimizer are one fused jit; the span times
         # host-side dispatch under the async loop and true step time
         # under the sync loop (monitor present / async_metrics off)
-        with tr.span("fwd_bwd_opt", "train",
-                     metric="train/fwd_bwd_opt_s"):
+        with tr.span("dispatch", "train", metric="train/dispatch_s"):
             params, opt_state, mx = step_fn(params, opt_state, batch)
         if tc.prefetch and step + 1 < tc.steps:
             # step is dispatched but not complete: stage the next batch now
@@ -199,6 +207,7 @@ def train(cfg: ModelConfig, tc: TrainerConfig,
             if host is None:
                 jax.block_until_ready(mx["loss"])
             result.compile_time_s = time.time() - t0
+            compiled_by_step0 = compiles.count()
         if monitor is not None:
             t_now = time.time()
             monitor.record_step(flops=step_flops,
@@ -251,6 +260,7 @@ def train(cfg: ModelConfig, tc: TrainerConfig,
         metrics.counter("train/tokens").inc(
             tc.steps * tc.batch * tc.seq_len)
     wall = time.time() - t0
+    result.loop_compiles = compiles.count() - compiled_by_step0
     result.steps_per_s = tc.steps / wall
     if tc.steps > 1 and wall > result.compile_time_s:
         result.steady_steps_per_s = (tc.steps - 1) / (wall -

@@ -143,6 +143,72 @@ def test_disabled_tracer_overhead_under_2pct():
     assert tr.events == []
 
 
+def _xplane_events(path):
+    """(plane, line, name, start_ns, dur_ns) of every event of the one
+    ``.xplane.pb`` that ``jax.profiler`` wrote under ``path``."""
+    from jax.profiler import ProfileData
+    (pb,) = list(path.rglob("*.xplane.pb"))
+    pd = ProfileData.from_file(str(pb))
+    return [(plane.name, line.name, e.name, e.start_ns, e.duration_ns)
+            for plane in pd.planes for line in plane.lines
+            for e in line.events]
+
+
+def test_enabled_spans_land_on_the_profiler_clock(tmp_path):
+    """An enabled tracer's spans (nested and detached) are also profiler
+    annotations named ``<cat>.<name>``, on the host plane and the same
+    clock as the ops they enclose; the in-memory events stay as they
+    were, and simulated-clock events are not bridged."""
+    tr = Tracer(enabled=True)
+    f = jax.jit(lambda x: (x @ x).sum())
+    x = jnp.ones((256, 256))
+    f(x).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        h = tr.begin("request", "serve")
+        with tr.span("outer", "test"):
+            f(x).block_until_ready()
+        tr.end(h)
+        tr.complete("restore", ts_s=1.0, dur_s=0.5, cat="sim")
+    finally:
+        jax.profiler.stop_trace()
+    ev = _xplane_events(tmp_path)
+    host = {name: (s, d) for plane, _, name, s, d in ev
+            if plane.startswith("/host:")}
+    assert "test.outer" in host and "serve.request" in host
+    assert "sim.restore" not in host and "restore" not in host
+    s0, d0 = host["test.outer"]
+    ops = [(s, d) for _, line, name, s, d in ev
+           if line.startswith("tf_XLA") and "dot" in name]
+    assert ops, "no dot op in the trace"
+    assert any(s0 <= s and s + d <= s0 + d0 for s, d in ops)
+    r0, rd = host["serve.request"]
+    assert r0 <= s0 and s0 + d0 <= r0 + rd
+    assert [e["name"] for e in tr.events] == ["outer", "request", "restore"]
+
+
+def test_compile_counter_counts_new_shapes_once():
+    from repro.obs import compiles
+    compiles.install()
+    compiles.install()                  # idempotent: one listener
+
+    def counted_fn(x):
+        return x * 3 + 1
+    f = jax.jit(counted_fn)
+    n0 = compiles.count()
+    f(jnp.ones(7)).block_until_ready()
+    new = [e for e in compiles.events()[n0:] if "counted_fn" in e.fun_name]
+    assert [e.kind for e in new] == ["trace", "compile"]
+    assert new[1].fun_name == "jit(counted_fn)"
+    assert all(e.end_s >= e.start_s for e in new)
+    n1 = compiles.count()
+    f(jnp.ones(7)).block_until_ready()   # same shape: nothing new
+    assert compiles.count() == n1
+    f(jnp.ones(9)).block_until_ready()   # a new shape compiles once
+    new = [e for e in compiles.events()[n1:] if "counted_fn" in e.fun_name]
+    assert [e.kind for e in new] == ["trace", "compile"]
+
+
 def test_span_metric_feeds_registry_histogram():
     reg = MetricsRegistry()
     tr = Tracer(enabled=True, registry=reg)
@@ -210,14 +276,6 @@ def test_metrics_dump_jsonl_schema(tmp_path):
     reg.dump_jsonl(str(path), meta={"arch": "opt-125m"})
     counts = validate_metrics_jsonl(str(path))
     assert counts == {"meta": 1, "metric": 3}
-
-
-def test_tracer_jsonl_event_log_validates(tmp_path, tracer):
-    with tracer.span("step", "train"):
-        pass
-    path = tmp_path / "events.jsonl"
-    tracer.save_jsonl(str(path))
-    assert validate_metrics_jsonl(str(path)) == {"event": 1}
 
 
 def test_validate_rejects_malformed(tmp_path):
@@ -361,17 +419,18 @@ def test_trainer_emits_phase_spans_and_metrics(tracer, tmp_path):
     reg = MetricsRegistry()
     tracer.registry = reg
     tc = TrainerConfig(steps=4, batch=2, seq_len=16, log_every=2)
-    train(_opt_tiny(), tc, metrics=reg)
+    res = train(_opt_tiny(), tc, metrics=reg)
+    assert res.loop_compiles == 0          # the step compiled once
 
     names = {e["name"] for e in tracer.events}
-    assert {"step", "data", "fwd_bwd_opt", "metrics_drain"} <= names
+    assert {"step", "data", "dispatch", "metrics_drain"} <= names
     steps = [e for e in tracer.events if e["name"] == "step"]
     assert len(steps) == 4
     assert [e["args"]["step"] for e in steps] == [0, 1, 2, 3]
     # phase spans nest inside their step span on the timeline
     s0 = steps[0]
     inner = [e for e in tracer.events
-             if e["name"] in ("data", "fwd_bwd_opt")
+             if e["name"] in ("data", "dispatch")
              and s0["ts"] <= e["ts"] <= s0["ts"] + s0["dur"]]
     assert inner, "no phase spans inside step 0"
 

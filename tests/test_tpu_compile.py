@@ -11,7 +11,10 @@ process may load the TPU library, and every pytest worker imports every
 test file.  Keep all chip-compile tests in this one file.
 """
 
+import dataclasses
 import os
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -131,3 +134,41 @@ def test_quant8_compiles(one_chip, op):
                                            interpret=False),
             sds((n,), jnp.int8), sds((n // 256,), jnp.float32))
     assert "tpu_custom_call" in txt
+
+
+def test_train_step_kernels_keep_their_names_and_scope(one_chip,
+                                                        monkeypatch):
+    """The whole training step at opt-125m widths (2 layers, batch 1,
+    S 2048, ``attn_impl="pallas"``): the benchmark's kernel readers still
+    find the FA-2 forward and backward instructions by name and output
+    dtype, and those instructions carry the ``attention_core`` scope."""
+    from repro.configs import get_config
+    from repro.kernels.flash_attention import ops
+    from repro.models import params as PM
+    from repro.optim import adamw
+    from repro.train.step import make_train_step
+    root = Path(__file__).resolve().parents[1]
+    monkeypatch.syspath_prepend(str(root))
+    from benchmarks.chip import harness
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)   # compile, not interpret
+    cfg = dataclasses.replace(get_config("opt-125m"), num_layers=2)
+    oc = adamw.OptConfig()
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,  # noqa: E731
+                                         sharding=one_chip)
+    params = jax.tree.map(sds, jax.eval_shape(
+        lambda k: PM.init_params(cfg, k), jax.random.PRNGKey(0)))
+    opt = jax.tree.map(sds, jax.eval_shape(
+        lambda p: adamw.init_opt_state(p, oc), params))
+    batch = {k: jax.ShapeDtypeStruct((1, 2048), jnp.int32, sharding=one_chip)
+             for k in ("tokens", "labels")}
+    txt = _compiled_text(make_train_step(cfg, oc, attn_impl="pallas"),
+                         params, opt, batch)
+    lines = [ln.strip() for ln in txt.splitlines()]
+    for reader, least in (("attn_fwd_roofline.train", 1),
+                          ("attn_bwd_roofline.train", 2)):
+        rx = re.compile(harness.metric_reader(reader).PATTERN)
+        hits = [ln for ln in lines if rx.search(ln)]
+        assert len(hits) >= least, reader
+        for ln in hits:
+            op = re.search(r'op_name="([^"]*)"', ln)
+            assert op and "/attention_core/" in op.group(1), ln[:200]
