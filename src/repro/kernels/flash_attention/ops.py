@@ -2,7 +2,9 @@
 
 Differentiable end-to-end: ``flash_attention_bhsd`` carries a custom VJP
 (fused FA-2 Pallas backward), so ``attn_impl="pallas"`` is a valid training
-path, not just an inference path.
+path, not just an inference path.  The kernels' tiles are chosen from the
+shapes (``kernel.pick_tiles``), so callers pass none; tiles that causality
+or the window leave without a live pair fetch nothing and do no work.
 """
 
 from __future__ import annotations
@@ -22,7 +24,6 @@ def _on_tpu() -> bool:
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0,
                     scale: Optional[float] = None,
-                    block_q: int = 128, block_k: int = 128,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Flash attention in (B,S,H,D) layout with GQA support.
 
@@ -37,6 +38,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
     out = flash_attention_bhsd(qt, kt, vt, causal=causal, window=window,
-                               scale=scale, block_q=block_q, block_k=block_k,
-                               interpret=interpret)
+                               scale=scale, interpret=interpret)
     return jnp.swapaxes(out, 1, 2)

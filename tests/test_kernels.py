@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.flash_attention import kernel as fa_kernel
 from repro.kernels.flash_attention.chunked import chunked_attention
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_reference
@@ -78,6 +79,135 @@ def test_flash_attention_pallas_grad_bf16_runs():
     assert gq.dtype == jnp.bfloat16 and gk.dtype == jnp.bfloat16 \
         and gv.dtype == jnp.bfloat16
     assert np.isfinite(np.asarray(gq, np.float32)).all()
+
+
+# Lengths the tile rule covers with one tile, forced through several:
+# B, S, H, K, D, causal, window, block_q, block_k
+FA_TILED_CASES = [
+    (1, 512, 4, 2, 64, True, 0, 128, 128),      # 4 x 4 tiles, GQA
+    (1, 640, 2, 1, 64, True, 200, 128, 256),    # window, GQA 2
+    (1, 520, 4, 2, 64, True, 0, 256, 128),      # padded q and kv
+    (1, 520, 2, 2, 64, False, 0, 128, 256),     # non-causal, padded kv
+    (1, 768, 2, 1, 64, True, 300, 256, 128),    # window over 3 q tiles
+]
+
+
+def _bhsd_pallas(causal, window, block_q, block_k):
+    """flash_attention_bhsd in (B,S,H,D) layout with forced tiles."""
+    def f(q, k, v):
+        t = lambda x: jnp.swapaxes(x, 1, 2)  # noqa: E731
+        return t(fa_kernel.flash_attention_bhsd(
+            t(q), t(k), t(v), causal=causal, window=window,
+            block_q=block_q, block_k=block_k, interpret=True))
+    return f
+
+
+@pytest.mark.parametrize("case", FA_TILED_CASES)
+def test_flash_attention_pallas_tiled_vs_ref(case):
+    """Online softmax across tiles, dead-tile clamping, diagonal-only
+    masking and padding, against the oracle."""
+    B, S, H, K, D, causal, window, bq, bk = case
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (B, S, H, D))
+    k = jax.random.normal(ks[1], (B, S, K, D))
+    v = jax.random.normal(ks[2], (B, S, K, D))
+    out = _bhsd_pallas(causal, window, bq, bk)(q, k, v)
+    ref = attention_reference(q, k, v, causal=causal, window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-6, atol=2e-6)
+
+
+@pytest.mark.parametrize("case", FA_TILED_CASES)
+def test_flash_attention_pallas_tiled_grad_vs_chunked(case):
+    B, S, H, K, D, causal, window, bq, bk = case
+    ks = jax.random.split(jax.random.PRNGKey(9), 4)
+    q = jax.random.normal(ks[0], (B, S, H, D))
+    k = jax.random.normal(ks[1], (B, S, K, D))
+    v = jax.random.normal(ks[2], (B, S, K, D))
+    w = jax.random.normal(ks[3], (B, S, H, D))
+    f = _bhsd_pallas(causal, window, bq, bk)
+    fp = lambda *a: (f(*a) * w).sum()                                  # noqa
+    fc = lambda *a: (chunked_attention(*a, causal=causal, window=window,  # noqa
+                                       chunk=64) * w).sum()
+    gp = jax.grad(fp, argnums=(0, 1, 2))(q, k, v)
+    gc = jax.grad(fc, argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), gp, gc):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-3, atol=1e-3, err_msg=name)
+
+
+@pytest.mark.parametrize("causal,window", [(True, 0), (True, 200),
+                                           (False, 0), (False, 300)])
+@pytest.mark.parametrize("q_len,kv_len,bq,bk", [
+    (512, 512, 128, 128), (520, 520, 256, 128), (640, 640, 128, 256),
+    (300, 700, 128, 256), (700, 600, 256, 128)])
+def test_live_tile_bounds_match_the_element_mask(causal, window, q_len,
+                                                 kv_len, bq, bk):
+    """``_block_live`` is exactly "some pair of the tile is unmasked" (its
+    rows include q padding, as the kernels compute them; its keys do
+    not), ``_q_bounds`` inverts it, and ``_block_full`` is exactly "no
+    pair of the tile is masked".  Every query has a key in these shapes
+    (a tile whose window holds only kv padding counts as live)."""
+    g = fa_kernel._Geom(causal, window, bq, bk, q_len, kv_len)
+    nq, nk = -(-q_len // bq), -(-kv_len // bk)
+    qp = np.arange(nq * bq)[:, None]
+    kp = np.arange(nk * bk)[None, :]
+    ok = (kp < kv_len) & (qp >= 0)
+    if causal:
+        ok = ok & (kp <= qp)
+    if window:
+        ok = ok & (kp > qp - window)
+    for qi in range(nq):
+        for ki in range(nk):
+            tile = ok[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
+            live = bool(fa_kernel._block_live(qi, ki, g))
+            assert live == bool(tile.any()), (qi, ki)
+            lo, hi = fa_kernel._q_bounds(ki, g)
+            assert live == (int(lo) <= qi <= int(hi)), (qi, ki)
+            assert bool(fa_kernel._block_full(qi, ki, g)) == \
+                bool(tile.all()), (qi, ki)
+
+
+@pytest.mark.parametrize("S,D,H,K,window", [
+    (2048, 64, 12, 12, 0), (4096, 128, 28, 4, 0), (8192, 128, 32, 8, 4096),
+    (130, 96, 2, 2, 0), (520, 64, 4, 2, 0), (1920, 64, 4, 4, 0),
+    (256, 64, 8, 2, 128), (640, 64, 2, 1, 200), (32768, 128, 8, 8, 0),
+    (4096, 256, 8, 8, 0)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_tile_rule(S, D, H, K, window, dtype):
+    """Multiples of 128 that divide the 128-padded length, at most that
+    length, within the window by less than one 128 tile, within the VMEM
+    budget; a function of the shapes alone."""
+    tiles = fa_kernel.pick_tiles(S, S, D, D, window=window, dtype=dtype)
+    padded = -(-S // 128) * 128
+    itemsize = np.dtype(dtype).itemsize
+    for name, (bq, bk) in tiles._asdict().items():
+        for b in (bq, bk):
+            assert b % 128 == 0 and b <= padded and padded % b == 0
+            if window:
+                assert b < window + 128
+        assert fa_kernel._vmem_bytes(name, bq, bk, D, D, itemsize) \
+            <= fa_kernel.VMEM_BUDGET
+    assert fa_kernel.pick_tiles(np.int64(S), S, D, D, window=window,
+                                dtype=jnp.dtype(dtype)) == tiles
+    if S >= 2048 and D <= 128:      # every kernel's target fits
+        assert tiles == fa_kernel.Tiles(**fa_kernel.TILE_TARGETS)
+
+
+def test_tile_rule_is_what_every_caller_runs():
+    """The (B,S,H,D) wrapper and the (B,H,S,D) entry point run the same
+    rule's tiles: bit-equal to forcing them."""
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    q = jax.random.normal(ks[0], (1, 384, 4, 64))
+    k = jax.random.normal(ks[1], (1, 384, 2, 64))
+    v = jax.random.normal(ks[2], (1, 384, 2, 64))
+    bq, bk = fa_kernel.pick_tiles(384, 384, 64, 64, window=0,
+                                  dtype=q.dtype).fwd
+    a = flash_attention(q, k, v, causal=True, interpret=True)
+    b = _bhsd_pallas(True, 0, bq, bk)(q, k, v)
+    c = _bhsd_pallas(True, 0, None, None)(q, k, v)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
 
 
 def test_train_step_accepts_donated_buffers():

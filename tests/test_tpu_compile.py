@@ -61,16 +61,19 @@ def _compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-# B, S, H, K, D: opt-125m (12 heads of 64) and qwen2-7b (28/4 heads of 128)
+# B, S, H, K, D, window: opt-125m (12 heads of 64), qwen2-7b (28/4 heads
+# of 128) and mixtral-8x7b's attention (32/8 heads of 128, 4096 window),
+# each at the tiles ``pick_tiles`` chooses for it
 FA_SHAPES = {
-    "opt-125m-s1024": (8, 1024, 12, 12, 64),
-    "opt-125m-s2048": (8, 2048, 12, 12, 64),
-    "qwen2-7b-s2048": (1, 2048, 28, 4, 128),
+    "opt-125m-s1024": (8, 1024, 12, 12, 64, 0),
+    "opt-125m-s2048": (8, 2048, 12, 12, 64, 0),
+    "qwen2-7b-s2048": (1, 2048, 28, 4, 128, 0),
+    "mixtral-w4096-s8192": (1, 8192, 32, 8, 128, 4096),
 }
 
 
 def _qkv(one_chip, shape):
-    B, S, H, K, D = shape
+    B, S, H, K, D, _ = shape
     sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16,  # noqa: E731
                                           sharding=one_chip)
     return sds(B, S, H, D), sds(B, S, K, D), sds(B, S, K, D)
@@ -78,8 +81,9 @@ def _qkv(one_chip, shape):
 
 @pytest.mark.parametrize("name", list(FA_SHAPES))
 def test_flash_attention_forward_compiles(one_chip, name):
+    window = FA_SHAPES[name][-1]
     txt = _compiled_text(
-        lambda q, k, v: flash_attention(q, k, v, causal=True,
+        lambda q, k, v: flash_attention(q, k, v, causal=True, window=window,
                                         interpret=False),
         *_qkv(one_chip, FA_SHAPES[name]))
     assert "tpu_custom_call" in txt
@@ -87,8 +91,11 @@ def test_flash_attention_forward_compiles(one_chip, name):
 
 @pytest.mark.parametrize("name", list(FA_SHAPES))
 def test_flash_attention_backward_compiles(one_chip, name):
+    window = FA_SHAPES[name][-1]
+
     def loss(q, k, v):
-        out = flash_attention(q, k, v, causal=True, interpret=False)
+        out = flash_attention(q, k, v, causal=True, window=window,
+                              interpret=False)
         return out.astype(jnp.float32).sum()
     txt = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)),
                          *_qkv(one_chip, FA_SHAPES[name]))
