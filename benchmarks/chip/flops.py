@@ -16,32 +16,7 @@ Conventions, fixed here so that no change of implementation moves them:
 
 from __future__ import annotations
 
-from typing import Any, Dict
-
-from benchmarks.chip.weights import dims
-
-
-def matmul_params(conf: Dict[str, Any]) -> int:
-    """Weights that take part in a matrix product per token: every
-    layer's projections and MLP, and the output head."""
-    n = dims(conf)
-    d, H, K, hd, f = n["d"], n["H"], n["K"], n["hd"], n["f"]
-    attn = d * H * hd + 2 * d * K * hd + H * hd * d
-    mlp = (3 if conf["activation_function"] == "silu" else 2) * d * f
-    return n["L"] * (attn + mlp) + d * n["V"]
-
-
-def attn_pair_flops(conf: Dict[str, Any]) -> int:
-    """Operations per (query, key) pair, summed over heads and layers."""
-    n = dims(conf)
-    return 4 * n["H"] * n["hd"] * n["L"]
-
-
-def train_flops_per_token(conf: Dict[str, Any], seq: int) -> float:
-    """Forward and backward of one token of a causal sequence of ``seq``."""
-    pairs_per_token = (seq + 1) / 2
-    return 3 * (2 * matmul_params(conf)
-                + attn_pair_flops(conf) * pairs_per_token)
+from typing import Dict
 
 
 def attention_cost(B: int, S: int, H: int, K: int, hd: int, *,

@@ -1,18 +1,21 @@
 """What every run shares: finding a cell's files by name, the chip's
 memory peak, and the result line.
 
-Nothing here imports JAX.  A cell (``BENCHMARK.json`` ``workloads``)
-names a configuration and a traffic mix; the harness finds
+Nothing here imports JAX (a family it loads may).  A cell
+(``BENCHMARK.json`` ``workloads``) names a configuration and a traffic
+mix; the harness finds
 
 * the configuration at the ``file`` its ``configs`` entry gives,
+* the configuration's model family, which its ``family`` key names, at
+  ``families/<family>.py`` (what it gives: ``families/decoder.py``),
 * the traffic mix at ``traffic/<traffic>.json``, whose ``runner`` key
   names the runner that drives the program, ``runners/<runner>.py``,
   which holds ``run`` (one run of the cell) and ``calibrate`` (the
   readings its limits are set from),
 * each per-layer metric's reader at ``metrics/<metric>.py``,
 
-so a new cell, configuration, mix or metric is new files and a new
-entry, and no edit.
+so a new cell, configuration, family, mix, job or metric is new files
+and a new entry, and no edit.
 """
 
 from __future__ import annotations
@@ -26,6 +29,8 @@ from typing import Any, Dict, List, Optional
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
+if str(ROOT / "src") not in sys.path:       # the program under test
+    sys.path.insert(0, str(ROOT / "src"))
 
 
 @dataclass
@@ -33,6 +38,7 @@ class Cell:
     name: str
     chips: int
     config: Dict[str, Any]
+    family: Any                 # the module families/<config's family>.py
     traffic: Dict[str, Any]
     end_to_end: List[Dict[str, Any]]
     per_layer: List[Dict[str, Any]]
@@ -69,6 +75,7 @@ def find_cell(bench: Dict[str, Any], name: str, root: Path = ROOT,
     w = cells[name]
     confs = {c["name"]: c for c in bench["configs"]}
     config = load_json(root / confs[w["config"]]["file"])
+    family = load_module(here / "families" / f"{config['family']}.py")
     traffic = load_json(here / "traffic" / f"{w['traffic']}.json")
     e2e = [m for m in bench["end_to_end"]
            if "workloads" not in m or name in m["workloads"]]
@@ -76,7 +83,7 @@ def find_cell(bench: Dict[str, Any], name: str, root: Path = ROOT,
     per_layer = [m for m in bench["per_layer"]
                  if (name in m["workloads"] if "workloads" in m
                      else m["moves"] in e2e_names)]
-    return Cell(name, w["chips"], config, traffic, e2e, per_layer)
+    return Cell(name, w["chips"], config, family, traffic, e2e, per_layer)
 
 
 def load_module(path: Path):

@@ -12,8 +12,6 @@ from __future__ import annotations
 import math
 from typing import Any, Dict, Tuple
 
-from benchmarks.chip.weights import dims, layer_specs, top_specs
-
 
 def mlp_cost(T: int, d: int, f: int, *, gated: bool,
              io_bytes: int = 2) -> Tuple[float, float]:
@@ -48,13 +46,12 @@ def adamw_cost(n_params: int) -> Tuple[float, float]:
     return 0.0, 32.0 * n_params
 
 
-def param_count(conf: Dict[str, Any]) -> int:
-    """Parameters of the configuration: every leaf of ``weights.py``."""
-    def leaves(specs):
-        for v in specs.values():
-            if isinstance(v, dict):
-                yield from leaves(v)
-            else:
-                yield math.prod(v[0])
-    return sum(leaves(top_specs(conf))) \
-        + dims(conf)["L"] * sum(leaves(layer_specs(conf)))
+def param_count(family, conf: Dict[str, Any]) -> int:
+    """Parameters of the configuration: every leaf of the family's
+    canonical weights (``family.weights``), counted from their shapes."""
+    import jax
+    import jax.numpy as jnp
+    from benchmarks.chip.seeded import seed_key
+    shapes = jax.eval_shape(lambda k: family.weights(conf, k, jnp.float32),
+                            jax.eval_shape(lambda: seed_key(0)))
+    return sum(math.prod(x.shape) for x in jax.tree.leaves(shapes))

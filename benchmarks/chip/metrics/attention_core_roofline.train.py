@@ -7,14 +7,13 @@ out, with its layout transposes and the backward's extra sums."""
 
 from benchmarks.chip import flops
 from benchmarks.chip.metrics import _scoped
-from benchmarks.chip.weights import dims
 
 LAYER = "attention core (repro.models.layers.run_attention)"
 MOVES = "train_tokens_per_s"
 
 
 def read(obs):
-    n, job = dims(obs["config"]), obs["traffic"]
+    n, job = obs["family"].dims(obs["config"]), obs["traffic"]
     args = (job["batch"], job["seq_len"], n["H"], n["K"], n["hd"])
     fwd = flops.attention_cost(*args)
     bwd = flops.attention_cost(*args, backward=True)

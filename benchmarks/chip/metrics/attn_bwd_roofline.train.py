@@ -5,13 +5,13 @@ work (twice the forward's products; the recomputed scores do not count)
 over the two kernels' summed device time.
 
 In the trace both are instructions named after ``flash_attention_bhsd``
-that return f32 gradients."""
+whose ``op_name`` in the timed step lies under ``transpose(``."""
 
 from benchmarks.chip.metrics import _attention
 
 LAYER = "kernels (repro.kernels.flash_attention)"
 MOVES = "train_tokens_per_s"
-PATTERN = r"^%?\S*flash_attention\S* = \(?f32\["
+PATTERN = _attention.PATTERN
 
 
 def read(obs):

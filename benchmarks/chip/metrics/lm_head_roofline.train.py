@@ -6,14 +6,13 @@ logits and the cross entropy)."""
 
 from benchmarks.chip import layer_costs
 from benchmarks.chip.metrics import _scoped
-from benchmarks.chip.weights import dims
 
 LAYER = "model step (repro.models)"
 MOVES = "train_tokens_per_s"
 
 
 def read(obs):
-    n, job = dims(obs["config"]), obs["traffic"]
+    n, job = obs["family"].dims(obs["config"]), obs["traffic"]
     cost = layer_costs.lm_head_cost(job["batch"] * job["seq_len"], n["d"],
                                     n["V"])
     return _scoped.share(obs, "lm_head", cost)

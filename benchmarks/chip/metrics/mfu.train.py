@@ -1,7 +1,8 @@
 """Model FLOP/s utilization of training: the forward and backward
-operations each token requires (``flops.train_flops_per_token``: 6 N
-without the input embedding, plus causal attention; nothing recomputed)
-times tokens per second of the untraced window, over the chip's peak."""
+operations each token requires (the family's ``train_flops_per_token``:
+6 N without the input embedding, plus causal attention; nothing
+recomputed) times tokens per second of the untraced window, over the
+chip's peak."""
 
 from benchmarks.chip import peaks
 

@@ -6,7 +6,6 @@ both products, the activation and the residual)."""
 
 from benchmarks.chip import layer_costs
 from benchmarks.chip.metrics import _scoped
-from benchmarks.chip.weights import dims
 
 LAYER = "model step (repro.models)"
 MOVES = "train_tokens_per_s"
@@ -14,7 +13,7 @@ MOVES = "train_tokens_per_s"
 
 def read(obs):
     conf, job = obs["config"], obs["traffic"]
-    n = dims(conf)
+    n = obs["family"].dims(conf)
     fl, by = layer_costs.mlp_cost(
         job["batch"] * job["seq_len"], n["d"], n["f"],
         gated=conf["activation_function"] == "silu")

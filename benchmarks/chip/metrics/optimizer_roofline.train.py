@@ -12,5 +12,6 @@ MOVES = "train_tokens_per_s"
 
 
 def read(obs):
-    cost = layer_costs.adamw_cost(layer_costs.param_count(obs["config"]))
+    cost = layer_costs.adamw_cost(
+        layer_costs.param_count(obs["family"], obs["config"]))
     return _scoped.share(obs, "optimizer", cost)

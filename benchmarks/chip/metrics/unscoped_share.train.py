@@ -1,6 +1,6 @@
 """Share of the training step's device time spent in ops under none of
-the program's named scopes (``scopes.SCOPES``): the work no per-layer
-metric can see (the final norm, the layer scan's plumbing)."""
+the program's named scopes (the family's ``SCOPES``): the work no
+per-layer metric can see (the final norm, the layer scan's plumbing)."""
 
 from benchmarks.chip import scopes
 

@@ -12,6 +12,10 @@ AdamW's first moment after one step) and change of the parameters after
 window.  After the window the float32 reference replays those steps from
 the same weights and batches, and ``compare.train_readings`` decides
 ``correct``.
+
+Everything that depends on the model (its ``ModelConfig``, weights,
+layout, reference, operation count and scopes) comes from the cell's
+family (``cell.family``), so any family's configuration trains here.
 """
 
 from __future__ import annotations
@@ -22,11 +26,12 @@ from collections import deque
 from pathlib import Path
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
-from benchmarks.chip import compare, flops, harness, program, tracing
-from benchmarks.chip import weights as W
-from benchmarks.chip.references import decoder as D
+from benchmarks.chip import compare, harness, tracing
+from benchmarks.chip.references import adamw as A
+from benchmarks.chip.seeded import flatten, seed_key
 
 TRACE_S = 3.0               # length of the traced slice after the window
+STEP_MODULE = "jit_train_step"   # the step's modules, as the trace names them
 
 
 def _opt_config(opt: Dict[str, Any]):
@@ -63,19 +68,20 @@ class Trainer:
         from repro.data.pipeline import make_batch_fn
         from repro.optim import adamw
         from repro.train.trainer import TrainerConfig, make_jit_train_step
-        self.conf, self.job = cell.config, cell.traffic
-        self.cfg = program.model_config(self.conf)
+        self.conf, self.job, self.family = cell.config, cell.traffic, \
+            cell.family
+        self.cfg = self.family.model_config(self.conf)
         self.B, self.S = self.job["batch"], self.job["seq_len"]
         oc = _opt_config(self.job["optimizer"])
         tc = TrainerConfig(batch=self.B, seq_len=self.S,
                            attn_impl=self.job["attn_impl"],
                            donate=fault is None, seed=seed)
-        self.key = W.seed_key(seed)
+        self.key = seed_key(seed)
         dtype = jnp.dtype(self.conf["param_dtype"])
-        conf, cfg = self.conf, self.cfg
+        conf, cfg, fam = self.conf, self.cfg, self.family
         # one jitted call makes every weight on the device from the seed
-        self.params = jax.jit(lambda k: program.to_program(
-            conf, W.all_weights(conf, k, dtype), cfg))(self.key)
+        self.params = jax.jit(lambda k: fam.to_program(
+            conf, fam.weights(conf, k, dtype), cfg))(self.key)
         self.opt_state = jax.jit(
             lambda p: adamw.init_opt_state(p, oc))(self.params)
         self.step = _planted(make_jit_train_step(cfg, tc, oc), fault)
@@ -114,13 +120,12 @@ class Trainer:
             self.fed.append(host)
             losses.append(m["loss"])
             if i == 0:     # AdamW's first moment after one step: (1-b1) g
-                mu = program.from_program(self.conf, self.opt_state["mu"],
-                                          self.cfg.vocab_size)
+                mu = self.family.from_program(self.conf,
+                                              self.opt_state["mu"], self.cfg)
                 grad1 = compare.norms(
                     {k: np.asarray(v, np.float64) / (1 - self.b1)
                      for k, v in jax.device_get(mu).items()})
-        after = program.from_program(self.conf, self.params,
-                                     self.cfg.vocab_size)
+        after = self.family.from_program(self.conf, self.params, self.cfg)
         return {"losses": [float(x) for x in jax.device_get(losses)],
                 "grad_norms": grad1,
                 "params_after": jax.device_get(after),
@@ -186,33 +191,33 @@ def slowest(steps: Sequence[Tuple[int, float, float, float, float]],
     return sorted(steps, key=lambda s: -(s[2] + s[3] + s[4]))[:top]
 
 
-def reference(conf: Dict[str, Any], job: Dict[str, Any], seed: int,
-              batches, *, lowp: bool = False) -> Dict[str, Any]:
+def reference(cell: harness.Cell, seed: int, batches, *,
+              lowp: bool = False) -> Dict[str, Any]:
     """The float32 reference (float8 products with ``lowp``) replaying
     the first steps from the same weights and batches."""
     import jax
     import jax.numpy as jnp
-    opt = job["optimizer"]
-    w0 = jax.jit(lambda k: W.all_weights(conf, k, jnp.float32))(
-        W.seed_key(seed))
+    conf, fam, opt = cell.config, cell.family, cell.traffic["optimizer"]
+    w0 = jax.jit(lambda k: fam.weights(conf, k, jnp.float32))(
+        seed_key(seed))
     w = w0
     m = v = jax.jit(lambda x: jax.tree.map(jnp.zeros_like, x))(w0)
     losses, grad1, grad_s = [], None, 0.0
     with jax.default_matmul_precision("highest"):
         for i, b in enumerate(batches):
             tg = time.perf_counter()
-            loss, g = D.loss_and_grad(w, b["tokens"], b["labels"], conf,
-                                      lowp=lowp)
+            loss, g = fam.loss_and_grad(w, b["tokens"], b["labels"], conf,
+                                        lowp=lowp)
             losses.append(float(loss))
             grad_s += time.perf_counter() - tg
-            w, m, v, gc_ = D.adamw_step(w, g, m, v, i + 1, opt,
+            w, m, v, gc_ = A.adamw_step(w, g, m, v, i + 1, opt,
                                         opt["no_decay"])
             if i == 0:
-                grad1 = compare.norms(D.flatten(gc_))
+                grad1 = compare.norms(flatten(gc_))
     delta = jax.jit(lambda a, b: jax.tree.map(jnp.subtract, a, b))(
-        D.flatten(w), D.flatten(w0))
+        flatten(w), flatten(w0))
     return {"losses": losses, "grad_norms": grad1,
-            "update_norms": compare.norms(delta), "w0": D.flatten(w0),
+            "update_norms": compare.norms(delta), "w0": flatten(w0),
             "grad_s": grad_s}
 
 
@@ -221,6 +226,21 @@ def program_update_norms(first: Dict[str, Any], w0) -> Dict[str, float]:
     return compare.norms({k: np.asarray(first["params_after"][k], np.float64)
                           - np.asarray(w0[k], np.float64)
                           for k in first["params_after"]})
+
+
+def compiled_text(step, args):
+    """The optimized HLO text of ``step`` on the shapes, types and
+    placements of ``args``, as a call that compiles only when a reader
+    asks for it: the jitted step the window ran, so the compiler gives
+    the program that ran.  Lowered from shapes, it is a cache entry of its
+    own: the first traced run in a checkout compiles it once (outside
+    the window and set-up), later runs read it from the persistent
+    cache."""
+    import jax
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding,
+                                       weak_type=x.weak_type), args)
+    return lambda: step.lower(*shapes).compile().as_text()
 
 
 def calibrate(cell: harness.Cell, seeds: Sequence[int],
@@ -238,14 +258,13 @@ def calibrate(cell: harness.Cell, seeds: Sequence[int],
         first = tr.first_steps(cell.traffic["check_steps"])
         del tr
         gc.collect()
-        ref = reference(cell.config, cell.traffic, seed, first["batches"])
+        ref = reference(cell, seed, first["batches"])
         got = compare.train_readings(
             first["losses"], first["grad_norms"],
             program_update_norms(first, ref["w0"]), ref)
         yield {"seed": seed, "kind": fault or "program", **got}
         if seed in control:
-            low = reference(cell.config, cell.traffic, seed,
-                            first["batches"], lowp=True)
+            low = reference(cell, seed, first["batches"], lowp=True)
             got = compare.train_readings(low["losses"], low["grad_norms"],
                                          low["update_norms"], ref)
             yield {"seed": seed, "kind": "control", **got}
@@ -270,11 +289,14 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: bool,
           f"gc {win['gc_n']} passes {win['gc_s']:.4f}s",
           flush=True)
     obs: Dict[str, Any] = {"config": cell.config, "traffic": cell.traffic,
+                           "family": cell.family,
                            "device_kind": devices[0].device_kind,
                            "chips": len(devices),
                            "window": win,
-                           "flops_per_token": flops.train_flops_per_token(
-                               cell.config, tr.S)}
+                           "flops_per_token":
+                               cell.family.train_flops_per_token(
+                                   cell.config, tr.S),
+                           "step_module": STEP_MODULE}
     summary = None
     if trace:
         with tracing.capture(workdir / "trace"):
@@ -283,13 +305,15 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: bool,
         obs["trace"] = tracing.load(workdir / "trace")
         summary = tracing.summarize(obs["trace"], "bench.traced")
         obs["trace_summary"] = summary
+        obs["step_hlo"] = compiled_text(tr.step, (tr.params, tr.opt_state,
+                                                  tr.batch))
     peak = harness.memory_peak_bytes(devices)
     print(f"[train] memory_stats {[d.memory_stats() for d in devices]}",
           flush=True)
     del tr
     gc.collect()
     t_ref = time.perf_counter()
-    ref = reference(cell.config, cell.traffic, seed, first["batches"])
+    ref = reference(cell, seed, first["batches"])
     got = compare.train_readings(first["losses"], first["grad_norms"],
                                  program_update_norms(first, ref["w0"]), ref)
     checks = [harness.check(k, got[k], limits[k]) for k in limits]

@@ -27,12 +27,13 @@ def tiny_train_cell():
 
 def tiny_gqa_train_cell():
     """The training cell run on a configuration of the other decoder
-    family the reference and ``program.py`` cover (Qwen2's layout:
+    the reference and ``families/decoder.py`` cover (Qwen2's layout:
     RMSNorm, rotary positions, grouped-query attention with q/k/v biases,
     a SwiGLU MLP, an untied head), at a size a CPU test run can hold."""
     cell = tiny_train_cell()
     cell.config = {
         "name": "gqa-tiny", "source": "https://huggingface.co/Qwen/Qwen2-7B",
+        "family": "decoder",
         "hidden_size": 64, "num_hidden_layers": 2, "num_attention_heads": 4,
         "num_key_value_heads": 2, "head_dim": 16, "intermediate_size": 128,
         "vocab_size": 8192, "max_position_embeddings": 256,

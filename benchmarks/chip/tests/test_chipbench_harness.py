@@ -1,18 +1,24 @@
 """FLOP and byte counts against hand counts, the chip's memory peak, and
-the harness finding a cell's files by name."""
+the harness finding a cell's files by name, a new model family's among
+them."""
 
 import json
 import os
 import subprocess
 import sys
 
+import jax
 import pytest
 
-import chipbench_cells  # noqa: F401  (the repository root on sys.path)
+import chipbench_cells
 
 from benchmarks.chip import flops, harness, peaks
 
 CONF = harness.HERE / "configs"
+DECODER = harness.load_module(harness.HERE / "families" / "decoder.py")
+FAMILY_GIVES = ("SCOPES", "model_config", "weights", "to_program",
+                "from_program", "loss_and_grad", "train_flops_per_token",
+                "dims")
 
 
 def conf(name):
@@ -25,10 +31,11 @@ def test_opt_125m_counts_by_hand():
     c = conf("opt-125m")
     # per layer: q, k, v, o of 768 x 768, MLP 768 x 3072 twice
     layer = 4 * 768 * 768 + 2 * 768 * 3072
-    assert flops.matmul_params(c) == 12 * layer + 768 * 50272 == 123_543_552
-    assert flops.attn_pair_flops(c) == 4 * 12 * 64 * 12
+    assert DECODER.matmul_params(c) == 12 * layer + 768 * 50272 \
+        == 123_543_552
+    assert DECODER.attn_pair_flops(c) == 4 * 12 * 64 * 12
     # 6 N plus causal attention, 2049/2 keys per query on average
-    assert flops.train_flops_per_token(c, 2048) == \
+    assert DECODER.train_flops_per_token(c, 2048) == \
         3 * (2 * 123_543_552 + 36_864 * 2049 / 2) == 854_562_816
 
 
@@ -75,15 +82,34 @@ def test_memory_peak_counts_the_reserved_scratch():
 
 # ---------------------------------------------------- finding files by name
 
+# a new family as a file: the decoder under another name, whose program
+# names one scope more
+FAMILY = """from benchmarks.chip.families.decoder import *  # noqa: F401,F403
+from benchmarks.chip.families.decoder import SCOPES as _DECODER
+
+SCOPES = _DECODER + ("router",)
+"""
+
+
 def _mini_benchmark(tmp_path):
-    """A benchmark directory holding one new cell, configuration, mix and
-    metric, made of files alone."""
+    """A benchmark directory holding new cells, configurations, a family,
+    mixes and a metric, made of files alone.  ``m-2.tiny-train`` is the
+    training cell at the size of ``chipbench_cells.tiny_train_cell``,
+    its configuration of the new family."""
     here = tmp_path / "benchmarks" / "chip"
-    for d in ("configs", "traffic", "metrics", "runners", "limits"):
+    for d in ("configs", "families", "traffic", "metrics", "runners",
+              "limits"):
         (here / d).mkdir(parents=True)
-    (here / "configs" / "m-1.json").write_text(json.dumps({"name": "m-1"}))
+    (here / "families" / "decoder-x.py").write_text(FAMILY)
+    (here / "configs" / "m-1.json").write_text(
+        json.dumps({"name": "m-1", "family": "decoder-x"}))
+    tiny = chipbench_cells.tiny_train_cell()
+    (here / "configs" / "m-2.json").write_text(
+        json.dumps(dict(tiny.config, name="m-2", family="decoder-x")))
     (here / "traffic" / "mix-a.json").write_text(
         json.dumps({"runner": "echo", "rate": 3}))
+    (here / "traffic" / "tiny-train.json").write_text(
+        json.dumps(tiny.traffic))
     (here / "runners" / "echo.py").write_text(
         "def run(cell, *a, **k):\n    return cell.traffic['rate']\n\n\n"
         "def calibrate(cell, seeds, control, faults, devices):\n"
@@ -93,9 +119,12 @@ def _mini_benchmark(tmp_path):
         "LAYER = 'cache'\nMOVES = 'x_ms'\n\n\ndef read(obs):\n"
         "    return obs.get('hits')\n")
     bench = {
-        "configs": [{"name": "m-1", "file": "benchmarks/chip/configs/m-1.json"}],
+        "configs": [{"name": "m-1", "file": "benchmarks/chip/configs/m-1.json"},
+                    {"name": "m-2", "file": "benchmarks/chip/configs/m-2.json"}],
         "workloads": [{"name": "m-1.mix-a", "config": "m-1",
-                       "traffic": "mix-a", "chips": 1}],
+                       "traffic": "mix-a", "chips": 1},
+                      {"name": "m-2.tiny-train", "config": "m-2",
+                       "traffic": "tiny-train", "chips": 1}],
         "end_to_end": [{"name": "x_ms", "unit": "ms"},
                        {"name": "setup_s", "unit": "s"},
                        {"name": "y", "unit": "s", "workloads": ["other"]}],
@@ -106,7 +135,9 @@ def _mini_benchmark(tmp_path):
 def test_cell_found_from_files_alone(tmp_path):
     bench, here = _mini_benchmark(tmp_path)
     cell = harness.find_cell(bench, "m-1.mix-a", root=tmp_path, here=here)
-    assert cell.config == {"name": "m-1"}
+    assert cell.config == {"name": "m-1", "family": "decoder-x"}
+    assert cell.family.SCOPES == DECODER.SCOPES + ("router",)
+    assert all(hasattr(cell.family, k) for k in FAMILY_GIVES)
     assert cell.traffic["rate"] == 3
     assert [m["name"] for m in cell.end_to_end] == ["x_ms", "setup_s"]
     assert [m["name"] for m in cell.per_layer] == ["hits.serve"]
@@ -119,6 +150,15 @@ def test_cell_found_from_files_alone(tmp_path):
     assert reader.read({}) is None
     with pytest.raises(KeyError):
         harness.find_cell(bench, "no.such.cell", root=tmp_path, here=here)
+    # the benchmark's own training runner drives the new family's cell
+    cell = harness.find_cell(bench, "m-2.tiny-train", root=tmp_path,
+                             here=here)
+    limits = harness.load_json(harness.HERE / "limits"
+                               / "train.opt-125m.s2048.json")
+    out = harness.runner(cell).run(cell, 2 ** 33 + 7, 0.3, False, tmp_path,
+                                   jax.devices("cpu"), limits)
+    assert out.correct, out.checks
+    assert out.obs["family"] is cell.family
 
 
 def test_every_cell_of_the_benchmark_resolves():
@@ -128,6 +168,7 @@ def test_every_cell_of_the_benchmark_resolves():
         run = harness.runner(cell)
         assert callable(run.run) and callable(run.calibrate)
         assert (harness.HERE / "limits" / f"{cell.name}.json").exists()
+        assert all(hasattr(cell.family, k) for k in FAMILY_GIVES)
         names = {m["name"] for m in cell.end_to_end}
         assert "setup_s" in names and len(names) >= 2
         assert cell.per_layer
@@ -149,7 +190,8 @@ def test_importing_the_benchmark_describes_no_topology():
         "    if 'tests' in p.parts: continue\n"
         "    if p.parent == harness.HERE and p.stem != '__init__':\n"
         "        importlib.import_module('benchmarks.chip.' + p.stem)\n"
-        "    elif p.parent.name in ('metrics', 'runners', 'references'):\n"
+        "    elif p.parent.name in ('metrics', 'runners', 'references',\n"
+        "                           'families'):\n"
         "        harness.load_module(p)\n"
         "from jax._src import xla_bridge\n"
         "assert not xla_bridge._backends, xla_bridge._backends\n"

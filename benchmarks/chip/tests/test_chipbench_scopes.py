@@ -1,6 +1,9 @@
 """The scope readers on a small recorded trace: device self time split by
-the program's named scopes through the compiled step's op_name map, the
-new per-layer shares by hand count, and the compile counter's reader."""
+the program's named scopes through the op_name map of the step the
+runner timed, the new per-layer shares by hand count, and the compile
+counter's reader."""
+
+import types
 
 import pytest
 
@@ -8,7 +11,9 @@ import chipbench_cells  # noqa: F401  (the repository root on sys.path)
 
 from benchmarks.chip import flops, harness, layer_costs, peaks, scopes, \
     tracing
-from benchmarks.chip.weights import dims
+
+CELL = harness.find_cell(harness.load_benchmark(), "train.opt-125m.s2048")
+SCOPES = CELL.family.SCOPES
 
 MS = 1e6     # ns
 BODY = "jit(train_step)/jvp()/while/body/closed_call"
@@ -66,17 +71,19 @@ def recorded():
                          host=[("bench.traced", 0.0, 200 * MS)])
 
 
-def obs_for(trace):
-    cell = harness.find_cell(harness.load_benchmark(), "train.opt-125m.s2048")
-    return {"config": cell.config, "traffic": cell.traffic,
-            "device_kind": "TPU v5 lite", "chips": 1, "trace": trace,
-            "trace_summary": tracing.summarize(trace, "bench.traced")}
+def obs_for(trace, hlo=HLO):
+    """What the training runner hands the readers after a traced run,
+    its step's HLO text being ``hlo`` (no compile)."""
+    return {"config": CELL.config, "traffic": CELL.traffic,
+            "family": CELL.family, "device_kind": "TPU v5 lite", "chips": 1,
+            "trace": trace,
+            "trace_summary": tracing.summarize(trace, "bench.traced"),
+            "step_module": "jit_train_step", "step_hlo": lambda: hlo}
 
 
 @pytest.fixture
-def mapped(monkeypatch):
-    """The readers map the trace through ``HLO`` (no compile)."""
-    monkeypatch.setattr(scopes, "compiled_step_text", lambda obs: HLO)
+def mapped():
+    """The readers map the trace through ``HLO``."""
     return obs_for(recorded())
 
 
@@ -92,7 +99,7 @@ def mapped(monkeypatch):
     ("", (None, False)),
 ])
 def test_scope_of_takes_the_innermost_named_component(op_name, want):
-    assert scopes.scope_of(op_name) == want
+    assert scopes.scope_of(op_name, SCOPES) == want
 
 
 def test_fusion_takes_its_roots_op_name_and_every_instruction_maps():
@@ -114,10 +121,40 @@ def test_split_by_hand_and_scoped_plus_unscoped_is_the_step(mapped):
     for k, ms in want.items():
         assert s[k] == pytest.approx(ms / 1e3), k
     assert s["total"] == pytest.approx(0.076)
-    scoped = sum(scopes.scope_s(s, sc) for sc in scopes.SCOPES)
+    scoped = sum(scopes.scope_s(s, sc) for sc in SCOPES)
     assert scoped + s["unscoped"] == pytest.approx(s["total"], rel=1e-12)
     assert s["unmapped"] == 0.0
-    assert "scoped + unscoped 76.000 of the step's 76.000" in scopes.table(s)
+    assert "scoped + unscoped 76.000 of the step's 76.000" in \
+        scopes.table(s, SCOPES)
+
+
+def test_split_reads_the_runners_step_and_the_familys_scopes():
+    """Another job's step module (not ``jit_train_step``), and a family
+    whose program names a scope outside the decoder's: the split follows
+    the names ``obs`` carries, and that scope gets its own time."""
+    hlo = HLO.replace("jit_train_step", "jit_pipeline_step").replace(
+        '"jit(train_step)/optimizer/add"',
+        '"jit(pipeline_step)/stage_handoff/collective-permute"')
+    trace = recorded()
+    trace.modules[0] = [(n.replace("train", "pipeline"), a, d)
+                        for n, a, d in trace.modules[0]]
+    # a train step module in the slice is not the runner's and counts not
+    trace.modules[0].append(("jit_train_step(9)", 5 * MS, 4 * MS))
+    family = types.SimpleNamespace(SCOPES=SCOPES + ("stage_handoff",))
+    obs = dict(obs_for(trace, hlo), family=family,
+               step_module="jit_pipeline_step")
+    s = scopes.read(obs)
+    assert s["steps"] == 2
+    assert s["stage_handoff.fwd"] == pytest.approx(0.002)
+    assert "optimizer.fwd" not in s
+    assert s["unscoped"] == pytest.approx(0.017)
+    assert "stage_handoff 2.000/0.000" in scopes.table(s, family.SCOPES)
+    # under the decoder's six names the same op is unscoped
+    s = scopes.read(dict(obs_for(trace, hlo),
+                         step_module="jit_pipeline_step"))
+    assert s["unscoped"] == pytest.approx(0.019)
+    # and under the train step's name only the decoy, with no op, is found
+    assert scopes.read(dict(obs_for(trace, hlo), family=family)) is None
 
 
 def test_new_readers_by_hand(mapped):
@@ -126,7 +163,7 @@ def test_new_readers_by_hand(mapped):
         "lm_head_roofline.train", "optimizer_roofline.train",
         "unscoped_share.train")}
     conf, job = mapped["config"], mapped["traffic"]
-    n, pk = dims(conf), peaks.lookup("TPU v5 lite")
+    n, pk = CELL.family.dims(conf), peaks.lookup("TPU v5 lite")
     B, S, T = job["batch"], job["seq_len"], job["batch"] * job["seq_len"]
     core = n["L"] * sum(flops.roofline_s(
         *flops.attention_cost(B, S, n["H"], n["K"], n["hd"], backward=b), pk)
@@ -145,10 +182,9 @@ def test_new_readers_by_hand(mapped):
     assert read["unscoped_share.train"] == pytest.approx(100 * 17 / 76)
 
 
-def test_program_without_scopes_or_a_stale_map_reads_nothing(monkeypatch):
+def test_program_without_scopes_or_a_stale_map_reads_nothing():
     bare = "\n".join(line.split(", metadata=")[0] for line in HLO.splitlines())
-    monkeypatch.setattr(scopes, "compiled_step_text", lambda obs: bare)
-    obs = obs_for(recorded())
+    obs = obs_for(recorded(), bare)
     assert scopes.read(obs) is None
     for m in ("attention_core_roofline.train", "mlp_roofline.train",
               "lm_head_roofline.train", "optimizer_roofline.train",
@@ -156,17 +192,19 @@ def test_program_without_scopes_or_a_stale_map_reads_nothing(monkeypatch):
         assert harness.metric_reader(m).read(obs) is None
     # a map from another compile: the trace's instructions are not in it
     other = HLO.replace("fusion.4 ", "fusion.40 ").replace("dot.2 ", "dot.20 ")
-    monkeypatch.setattr(scopes, "compiled_step_text", lambda obs: other)
-    assert scopes.read(obs_for(recorded())) is None
-    # no trace at all (an untraced run)
+    assert scopes.read(obs_for(recorded(), other)) is None
+    # no trace at all (an untraced run): its step's text is never asked for
+    untraced = obs_for(recorded())
+    del untraced["trace"], untraced["trace_summary"]
+    untraced["step_hlo"] = lambda: pytest.fail("compiled without a trace")
+    assert scopes.read(untraced) is None
     assert scopes.read({"config": {}, "traffic": {}}) is None
 
 
-def test_failed_map_reads_nothing_and_does_not_raise(monkeypatch):
-    def boom(obs):
+def test_failed_map_reads_nothing_and_does_not_raise():
+    def boom():
         raise RuntimeError("no compile")
-    monkeypatch.setattr(scopes, "compiled_step_text", boom)
-    obs = obs_for(recorded())
+    obs = dict(obs_for(recorded()), step_hlo=boom)
     assert harness.metric_reader("mlp_roofline.train").read(obs) is None
     assert "scope_map_span_s" in obs
 
@@ -181,12 +219,11 @@ def test_layer_costs_by_hand():
     assert fl == 6 * 4 * 2 * 5
     assert by == 2 * (2 * 8 + 2 * 10 + 8) + 16 + 4 + 4 * 10
     assert layer_costs.adamw_cost(10) == (0.0, 320.0)
-    cell = harness.find_cell(harness.load_benchmark(), "train.opt-125m.s2048")
     # opt-125m: embed 50272x768, positions 2048x768, final norm 2x768, and
     # 12 layers of 2 norms (2x768 each), q/k/v/o 4x768x768, q/k/v biases
     # 3x768 and the MLP 2x768x3072
     layer = 2 * 1536 + 4 * 768 * 768 + 3 * 768 + 2 * 768 * 3072
-    assert layer_costs.param_count(cell.config) == \
+    assert layer_costs.param_count(CELL.family, CELL.config) == \
         50272 * 768 + 2048 * 768 + 1536 + 12 * layer == 125_182_464
 
 

@@ -1,6 +1,6 @@
 """The trace reduction on a small recorded trace: busy union, idle share,
-kernel time and roofline share by op name, self time of nested ops, and
-idle gaps named by the host span open over them."""
+kernel time and roofline share by op name and direction, self time of
+nested ops, and idle gaps named by the host span open over them."""
 
 import json
 
@@ -8,10 +8,11 @@ import pytest
 
 import chipbench_cells  # noqa: F401  (the repository root on sys.path)
 
-from benchmarks.chip import flops, harness, peaks, tracing
-from benchmarks.chip.weights import dims
+from benchmarks.chip import harness, tracing
+from benchmarks.chip.metrics import _attention
 
 MS = 1e6     # ns
+CELL = harness.find_cell(harness.load_benchmark(), "train.opt-125m.s2048")
 
 # one chip, a 100 ms window: a loop (10-60 ms) holding two kernels, a
 # fusion right after it, and a lone op after an idle gap
@@ -26,6 +27,15 @@ OPS = [
 ]
 HOST = [("bench.traced", 0.0, 100 * MS), ("bench.data", 70 * MS, 15 * MS),
         ("bench.step", 0.0, 9 * MS)]
+# the timed step's HLO: the kernels' op_names give their direction
+CORE = "while/body/closed_call/attention/attention_core/" \
+    "jit(flash_attention_bhsd)/pallas_call"
+HLO = f"""HloModule jit_train_step
+ENTRY %main.1 (a: bf16[8]) -> bf16[8] {{
+  %flash_attention_bhsd.27 = (bf16[6,12,2048,64]{{3,2,1,0}}, f32[6]) custom-call(%a), metadata={{op_name="jit(train_step)/jvp()/{CORE}"}}
+  %flash_attention_bhsd.28 = f32[6,12,2048,64]{{3,2,1,0}} custom-call(%b), metadata={{op_name="jit(train_step)/transpose(jvp())/{CORE}"}}
+}}
+"""
 
 
 def recorded():
@@ -58,19 +68,36 @@ def test_self_time_leaves_out_nested_ops():
         pytest.approx(10 * MS)
 
 
-def test_kernel_time_by_name():
+def obs_for(trace):
+    """What the training runner hands the readers after a traced run."""
+    return {"trace": trace,
+            "trace_summary": tracing.summarize(trace, "bench.traced"),
+            "config": CELL.config, "traffic": CELL.traffic,
+            "family": CELL.family, "device_kind": "TPU v5 lite",
+            "step_module": "jit_train_step", "step_hlo": lambda: HLO}
+
+
+@pytest.mark.parametrize("bwd_writes", ["f32", "bf16"])
+def test_kernel_time_by_name(bwd_writes):
+    """The kernels by name, their direction by the op_name map, whatever
+    dtype the backward writes; an op the map lacks counts for neither."""
+    from benchmarks.chip import scopes
     fwd = harness.metric_reader("attn_fwd_roofline.train").PATTERN
     bwd = harness.metric_reader("attn_bwd_roofline.train").PATTERN
-    assert tracing.op_ns(OPS, fwd, 0, 100 * MS) == (10 * MS, 1)
-    assert tracing.op_ns(OPS, bwd, 0, 100 * MS) == (20 * MS, 1)
+    ops = [(n.replace("= f32[", f"= {bwd_writes}["), a, d) for n, a, d in OPS]
+    names = scopes.hlo_op_names(HLO)
+    assert _attention.kernel_ns(ops, names, fwd, False, 0, 100 * MS) == \
+        10 * MS
+    assert _attention.kernel_ns(ops, names, bwd, True, 0, 100 * MS) == \
+        20 * MS
+    assert _attention.kernel_ns(ops, names, bwd, True, 35 * MS, 100 * MS) \
+        == 15 * MS
+    del names["flash_attention_bhsd.28"]
+    assert _attention.kernel_ns(ops, names, bwd, True, 0, 100 * MS) == 0
 
 
 def test_roofline_share_against_hand_count():
-    conf = harness.load_json(harness.HERE / "configs" / "opt-125m.json")
-    job = harness.load_json(harness.HERE / "traffic" / "train-s2048.json")
-    tr = recorded()
-    obs = {"trace": tr, "trace_summary": tracing.summarize(tr, "bench.traced"),
-           "config": conf, "traffic": job, "device_kind": "TPU v5 lite"}
+    obs = obs_for(recorded())
     # forward: 4 B H hd S(S+1)/2 = 4*6*12*64*2048*2049/2 per layer, 12
     # layers in the one step, against 10 ms of kernel time
     need = 12 * 4 * 6 * 12 * 64 * 2048 * 2049 / 2 / 197e12
@@ -85,12 +112,12 @@ def test_roofline_share_against_hand_count():
 def test_reader_without_its_kernel_reads_nothing():
     tr = recorded()
     tr.devices[0] = [op for op in OPS if "flash" not in op[0]]
-    obs = {"trace": tr, "trace_summary": tracing.summarize(tr, "bench.traced"),
-           "config": harness.load_json(harness.HERE / "configs" / "opt-125m.json"),
-           "traffic": harness.load_json(harness.HERE / "traffic" / "train-s2048.json"),
-           "device_kind": "TPU v5 lite"}
+    obs = obs_for(tr)
     assert harness.metric_reader("attn_fwd_roofline.train").read(obs) is None
     assert harness.metric_reader("attn_bwd_roofline.train").read(obs) is None
+    # nor a traced run whose step's op_name map could not be had
+    obs = dict(obs_for(recorded()), step_hlo=lambda: 1 / 0)
+    assert harness.metric_reader("attn_fwd_roofline.train").read(obs) is None
 
 
 def test_breakdown_is_json_with_at_most_ten_entries():

@@ -92,15 +92,6 @@ def busy_ns(events: Sequence[Event], t0: float, t1: float) -> float:
         (s, s + d) for _, s, d in clip(events, t0, t1)))
 
 
-def op_ns(events: Sequence[Event], pattern: str, t0: float,
-          t1: float) -> Tuple[float, int]:
-    """Summed device time and count of the operations whose name matches
-    ``pattern`` (a regular expression searched in the event name)."""
-    rx = re.compile(pattern)
-    hits = [(n, s, d) for n, s, d in clip(events, t0, t1) if rx.search(n)]
-    return sum(d for _, _, d in hits), len(hits)
-
-
 def short_name(name: str) -> str:
     """``%fusion.12 = f32[6,2048]{...} fusion(...)`` -> ``fusion.12
     f32[6,2048]``: the instruction and its result's leading shape."""
@@ -154,6 +145,16 @@ def idle_gaps(events: Sequence[Event], host: Sequence[Event], t0: float,
         label = max(open_spans)[2] if open_spans else "-"
         labelled.append((label, (b - a) / 1e9))
     return labelled
+
+
+def module_spans(modules: Sequence[Event], name: str, t0: float,
+                 t1: float) -> List[Tuple[float, float]]:
+    """(start, end) in ns of the modules named ``name`` (the ``XLA
+    Modules`` line names a jitted program ``name(<id>)``) that lie wholly
+    inside [t0, t1]."""
+    rx = re.compile(rf"^{re.escape(name)}\b")
+    return [(a, a + d) for n, a, d in modules
+            if rx.search(n) and a >= t0 and a + d <= t1]
 
 
 def window(host: Sequence[Event], name: str) -> Optional[Tuple[float, float]]:
